@@ -1,5 +1,5 @@
 """Quickstart: train a reduced SmolLM on synthetic data with the full
-production runner (journal + checkpoint + watchdog), then serve it.
+production runner (journal + checkpoint + resume), then serve it.
 
     PYTHONPATH=src python examples/quickstart.py
 """

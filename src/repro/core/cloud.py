@@ -41,14 +41,27 @@ import time
 from dataclasses import dataclass, field
 
 from repro.core.faults import FaultPlan, MonitorDaemon
-from repro.core.handler import Handler, HandlerTenant, SpeedBox
-from repro.core.manager import Manager, ManagerConfig, validate_scheduling
+from repro.core.handler import Handler, HandlerCrash, HandlerTenant, SpeedBox
+from repro.core.manager import (Manager, ManagerConfig, ManagerCrash,
+                                validate_scheduling)
 from repro.core.program import WorkloadProgram
 from repro.core.space import (ANY, CONTROL_SCHEMAS, DEFAULT_NAMESPACE,
-                              TSTimeout, TupleSpace, as_scoped, find_checked,
-                              find_crashpoint, find_raced, role)
+                              CrashPointFired, TSTimeout, TupleSpace,
+                              as_scoped, find_checked, find_crashpoint,
+                              find_raced, role)
 
-__all__ = ["ACANCloud", "CloudConfig", "CloudResult", "MultiCloudResult"]
+__all__ = ["ACANCloud", "CloudConfig", "CloudResult", "CloudRunError",
+           "MultiCloudResult"]
+
+#: What the fault plane injects. A Manager or Handler that dies of one of
+#: these is revived from TS state; any other exception fails the run.
+INJECTED_CRASHES = (ManagerCrash, HandlerCrash, CrashPointFired)
+
+
+class CloudRunError(RuntimeError):
+    """A Manager or Handler died of an exception that is not an injected
+    crash (chained as ``__cause__``), or a worker process exited with an
+    error of its own."""
 
 
 def _default_layers() -> list:
@@ -165,6 +178,12 @@ class CloudResult:
     #: run was race-free): one formatted line per unordered conflicting
     #: stage pair.
     race_report: list = field(default_factory=list)
+    #: The Manager published its finished flag. False: the wall limit
+    #: stopped the run first, and the histories are partial.
+    finished: bool = False
+    #: Tasks re-published after a barrier timeout, summed over every
+    #: Manager incarnation of this program.
+    reissues: int = 0
 
 
 @dataclass
@@ -185,6 +204,8 @@ class MultiCloudResult:
     ts_leaks: dict = field(default_factory=dict)
     #: PR 8: the whole shared space's race-sanitizer outcome.
     race_report: list = field(default_factory=list)
+    #: Every program finished (see CloudResult.finished).
+    finished: bool = False
 
 
 class ACANCloud:
@@ -292,20 +313,31 @@ class ACANCloud:
         )
         # Keep the latest incarnation: a revival replaces the Manager
         # object, and the cost_report surface must read the live model.
+        old = self._managers[i]
+        if old is not None:
+            self._reissued_retired[i] += old.reissued
         self._managers[i] = mgr
         suffix = f"-{self.namespaces[i]}" if self.multi else ""
-        th = threading.Thread(target=self._manager_body, args=(mgr,),
+        th = threading.Thread(target=self._role_body, args=(mgr.run,),
                               name=f"acan-manager{suffix}", daemon=True)
         th.start()
         return mgr, th
 
-    def _manager_body(self, mgr: Manager) -> None:
+    def _role_body(self, run) -> None:
+        """Run one Manager/Handler incarnation. An injected crash ends the
+        thread and the daemon revives a fresh one from TS state; any other
+        exception is a real failure: recorded, and the run stops."""
         try:
-            mgr.run()
-        except Exception:
-            # Crash (injected or real): thread dies; daemon revives a fresh
-            # Manager that resumes from the TS cursor.
+            run()
+        except INJECTED_CRASHES:
             return
+        except Exception as e:            # noqa: BLE001 — reported by run()
+            self._fail(f"thread {threading.current_thread().name}", e)
+
+    def _fail(self, where: str, exc: BaseException | None) -> None:
+        with self._fail_lock:
+            self._failures.append((where, exc))
+        self.stop_event.set()
 
     def handler_busy_time(self) -> float:
         """Total emulated compute seconds across the fleet, *including*
@@ -345,7 +377,7 @@ class ACANCloud:
                     crash_event=self._handler_crashes[i],
                     stop_event=self.stop_event)
         self._handlers[i] = h
-        th = threading.Thread(target=self._handler_body, args=(h,),
+        th = threading.Thread(target=self._role_body, args=(h.run,),
                               name=f"acan-{h.name}", daemon=True)
         th.start()
         return th
@@ -357,6 +389,13 @@ class ACANCloud:
         revival path calls this without knowing the difference."""
         from repro.core.workers import spawn_worker
         cfg = self.cfg
+        prev = self._handler_crashes[i].proc
+        if prev is not None and (prev.proc.returncode or 0) > 0:
+            # Exited with an error of its own (a kill by the fault plane
+            # or the shutdown gives a negative signal code): a real
+            # failure, not a crash to revive.
+            self._fail(f"worker {prev.name} exit code "
+                       f"{prev.proc.returncode}", None)
         hp = spawn_worker(
             self._server.addr, f"h{i}",
             speed=self._speed_boxes[i].get(),      # re-draws land here
@@ -368,13 +407,6 @@ class ACANCloud:
             tenant_caps=(cfg.tenant_caps or None) if self.multi else None)
         self._handler_crashes[i].proc = hp
         return hp
-
-    @staticmethod
-    def _handler_body(h: Handler) -> None:
-        try:
-            h.run()
-        except Exception:
-            return
 
     # ------------------------------------------------------------- results
     def _finished(self, i: int) -> bool:
@@ -451,10 +483,34 @@ class ACANCloud:
             cost_report=cost_report,
             race_report=([] if raced is None
                          else raced.race_report(self.namespaces[i])),
+            finished=self._finished(i),
+            reissues=self._reissued_retired[i] + (
+                self._managers[i].reissued
+                if self._managers[i] is not None else 0),
         )
 
+    def _wait_finished(self, deadline: float) -> None:
+        """Block until every Manager published its finished flag, the
+        deadline passed, or a role failed."""
+        for space in self.spaces:
+            while not self._failures:
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return              # wall limit hit — stop everything
+                try:
+                    space.read(("mstate", "finished"),
+                               timeout=min(left, self.FAILURE_QUANTUM))
+                    break
+                except TSTimeout:
+                    continue
+
     # ----------------------------------------------------------------- run
+    #: How often the wait for the finished flags looks for a failed role.
+    FAILURE_QUANTUM = 0.1
+
     def run(self) -> CloudResult | MultiCloudResult:
+        """Run every program to its finished flag or ``wall_limit``.
+        Raises :class:`CloudRunError` when a role died of a real error."""
         # The cloud's own TS ops (the blocking finished reads, the result
         # collection) run on the caller's thread — tag it for the
         # CheckedBackend role checks, restoring whatever it had.
@@ -483,7 +539,10 @@ class ACANCloud:
         self._speed_boxes = [SpeedBox(float(s)) for s in speeds]
         self._handlers: list[Handler | None] = [None] * cfg.n_handlers
         self._managers: list[Manager | None] = [None] * n_programs
+        self._reissued_retired = [0] * n_programs
         self._busy_retired = 0.0
+        self._failures: list[tuple[str, BaseException | None]] = []
+        self._fail_lock = threading.Lock()
 
         daemon = MonitorDaemon(
             plan=cfg.fault_plan,
@@ -512,24 +571,19 @@ class ACANCloud:
         dthread.start()
 
         # Wait for every Manager to publish its finished flag (revivals
-        # keep the jobs alive through crashes): one blocking read per
+        # keep the jobs alive through crashes): blocking reads per
         # namespace against the shared wall-limit deadline — each
-        # completion put wakes us directly. ("poll" scheduling keeps the
-        # busy-wait as the benchmark baseline.)
+        # completion put wakes us directly — sliced so that a failed
+        # role stops the run within FAILURE_QUANTUM. ("poll" scheduling
+        # keeps the busy-wait as the benchmark baseline.)
         deadline = t0 + cfg.wall_limit
         if cfg.scheduling == "poll":
             while not all(self._finished(i) for i in range(n_programs)):
-                if time.monotonic() > deadline:
+                if time.monotonic() > deadline or self._failures:
                     break
                 time.sleep(0.02)
         else:
-            for space in self.spaces:
-                try:
-                    space.read(("mstate", "finished"),
-                               timeout=max(deadline - time.monotonic(),
-                                           1e-3))
-                except TSTimeout:
-                    break               # wall limit hit — stop everything
+            self._wait_finished(deadline)
         self.stop_event.set()
         dthread.join(timeout=2.0)
         # Quiesce the fleet before the shutdown protocol scan: a handler
@@ -548,6 +602,12 @@ class ACANCloud:
         if self._server is not None:
             self._server.close()
         wall = time.monotonic() - t0
+        if self._failures:
+            where, exc = self._failures[0]
+            raise CloudRunError(
+                f"{where} failed: {exc!r}"
+                + (f" (+{len(self._failures) - 1} more)"
+                   if len(self._failures) > 1 else "")) from exc
 
         # Verify the shared hash chain and snapshot stats ONCE — the
         # ledger replay is O(total mutations) and identical for every
@@ -578,4 +638,5 @@ class ACANCloud:
                                   else list(report["violation_samples"])),
             ts_leaks={} if report is None else dict(report["leaks"]),
             race_report=[] if raced is None else raced.race_report(),
+            finished=all(r.finished for r in results),
         )

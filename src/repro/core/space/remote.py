@@ -200,6 +200,9 @@ class RemoteBackend:
         src_root = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env = dict(os.environ)
         env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
+        # Host-only child: it must never claim the accelerator that the
+        # parent process holds.
+        env["JAX_PLATFORMS"] = "cpu"
         # -c instead of -m: the package __init__ imports .server, and
         # runpy warns when the -m target is already in sys.modules.
         launcher = ("import sys; from repro.core.space.server import main; "

@@ -115,6 +115,9 @@ def spawn_worker(addr: tuple | str, name: str, *, speed: float = 1.0,
     src_root = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
+    # Host-only child: it must never claim the accelerator that the
+    # cloud's process holds.
+    env["JAX_PLATFORMS"] = "cpu"
     argv = [sys.executable, "-m", "repro.core.workers",
             "--addr", addr, "--name", name, "--speed", str(speed),
             "--capacity", str(capacity), "--lr", str(lr),

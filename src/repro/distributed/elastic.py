@@ -18,12 +18,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:
-    from jax.sharding import AxisType
-except ImportError:            # older jax: no explicit axis types
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.distributed import sharding as shd
 
@@ -39,8 +34,6 @@ def plan_mesh(devices: list, model_axis: int) -> Mesh:
     data = n // model
     used = devices[: data * model]
     arr = np.array(used).reshape(data, model)
-    if AxisType is None:
-        return Mesh(arr, ("data", "model"))
     return Mesh(arr, ("data", "model"),
                 axis_types=(AxisType.Auto, AxisType.Auto))
 

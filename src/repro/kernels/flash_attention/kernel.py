@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -96,7 +94,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     BH, G, Tq, D = q.shape
     Tkv = k.shape[1]
     bq, bk = min(bq, Tq), min(bk, Tkv)
-    assert Tq % bq == 0 and Tkv % bk == 0, (Tq, bq, Tkv, bk)
+    if Tq % bq or Tkv % bk:
+        raise ValueError(f"blocks ({bq}, {bk}) do not divide ({Tq}, {Tkv})")
     scale = 1.0 / (D ** 0.5)
 
     kern = functools.partial(_kernel, bq=bq, bk=bk, causal=causal,
@@ -118,7 +117,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -10,15 +10,13 @@ import jax.numpy as jnp
 from repro.kernels.ssd_scan.kernel import ssd_scan
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool = False):
     """x: (Bt, T, H, P); dt: (Bt, T, H); A, D: (H,); B, C: (Bt, T, G, N).
 
-    Returns (y (Bt, T, H, P), final_state (Bt, H, N, P))."""
+    Returns (y (Bt, T, H, P), final_state (Bt, H, N, P)). The kernel is
+    compiled for the TPU; ``interpret=True`` runs it in the Pallas
+    interpreter (CPU tests only)."""
     Bt, T, H, P = x.shape
     G, N = B.shape[-2], B.shape[-1]
     rep = H // G
@@ -31,6 +29,6 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 128):
     af = jnp.tile(A, Bt)
     df = jnp.tile(D, Bt)
     y, s = ssd_scan(xf, dtf, af, bf, cf, df, chunk=chunk,
-                    interpret=not _on_tpu())
+                    interpret=interpret)
     return (y.reshape(Bt, H, T, P).transpose(0, 2, 1, 3),
             s.reshape(Bt, H, N, P))

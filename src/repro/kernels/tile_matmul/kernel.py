@@ -12,7 +12,7 @@ into the forward task's epilogue (bias + activation applied in VMEM before
 the tile is written back) — one HBM round-trip instead of two.
 
 Block sizes must be multiples of the MXU/VREG tiling (128 lanes; 8
-sublanes fp32) for full utilisation; ops.py picks them.
+sublanes fp32) or the whole dim; ops.py picks them.
 """
 
 from __future__ import annotations
@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 _ACTS = {
     "none": lambda x: x,
@@ -65,9 +63,12 @@ def tile_matmul(x, w, b=None, *, activation: str = "none",
     """
     M, K = x.shape
     K2, N = w.shape
-    assert K == K2, (x.shape, w.shape)
+    if K != K2:
+        raise ValueError(f"contraction mismatch: {x.shape} @ {w.shape}")
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    if M % bm or N % bn or K % bk:
+        raise ValueError(f"blocks ({bm}, {bn}, {bk}) do not divide "
+                         f"({M}, {N}, {K})")
     out_dtype = out_dtype or x.dtype
     has_bias = b is not None
     if b is None:
@@ -87,7 +88,7 @@ def tile_matmul(x, w, b=None, *, activation: str = "none",
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, _k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, b2)

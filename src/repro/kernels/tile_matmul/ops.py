@@ -1,6 +1,5 @@
-"""Jitted public wrapper for tile_matmul: picks MXU-aligned block sizes,
-interpret mode off-TPU, and falls back to the jnp oracle for shapes the
-kernel's divisibility contract rejects."""
+"""Jitted public wrapper for tile_matmul: picks (8, 128)-legal block
+sizes and refuses shapes whose blocks would not fit VMEM."""
 
 from __future__ import annotations
 
@@ -9,29 +8,38 @@ from functools import partial
 import jax
 
 from repro.kernels.tile_matmul.kernel import tile_matmul
-from repro.kernels.tile_matmul.ref import tile_matmul_ref
+from repro.kernels.tiling import LANE, SUBLANE, pick_block
+
+#: Default scoped-VMEM limit of a v5e TensorCore (16 MiB): what one
+#: kernel's double-buffered blocks plus scratch may occupy.
+VMEM_BUDGET = 16 * 2**20
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _block_bytes(bm: int, bn: int, bk: int, itemsize: int,
+                out_itemsize: int) -> int:
+    """VMEM one grid step holds: double-buffered x/w/bias/out blocks plus
+    the fp32 accumulator."""
+    return (2 * (bm * bk + bk * bn + bn) * itemsize
+            + 2 * bm * bn * out_itemsize + bm * bn * 4)
 
 
-def _pick(dim: int, target: int) -> int:
-    b = min(target, dim)
-    while dim % b:
-        b //= 2
-    return max(b, 1)
-
-
-@partial(jax.jit, static_argnames=("activation", "bm", "bn", "bk"))
+@partial(jax.jit, static_argnames=("activation", "bm", "bn", "bk",
+                                   "interpret"))
 def matmul(x, w, b=None, *, activation: str = "none", bm: int = 256,
-           bn: int = 256, bk: int = 512):
-    """ACAN task-grid GEMM with fused bias+activation epilogue."""
+           bn: int = 256, bk: int = 512, interpret: bool = False):
+    """ACAN task-grid GEMM with fused bias+activation epilogue. The kernel
+    is compiled for the TPU; ``interpret=True`` runs it in the Pallas
+    interpreter (CPU tests only)."""
     M, K = x.shape
     N = w.shape[1]
-    bm, bn, bk = _pick(M, bm), _pick(N, bn), _pick(K, bk)
-    # VREG/MXU alignment: fall back to the oracle for degenerate tiles.
-    if min(bm, bn, bk) < 8:
-        return tile_matmul_ref(x, w, b, activation=activation)
+    # bm is a sublane dim (x, out); bn and bk are lane dims (w/out, x).
+    bm, bn, bk = (pick_block(M, bm, SUBLANE), pick_block(N, bn, LANE),
+                  pick_block(K, bk, LANE))
+    need = _block_bytes(bm, bn, bk, x.dtype.itemsize, x.dtype.itemsize)
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"tile_matmul cannot tile ({M}, {K}) @ ({K}, {N}): blocks "
+            f"(bm={bm}, bn={bn}, bk={bk}) need {need} B of VMEM, over "
+            f"{VMEM_BUDGET}")
     return tile_matmul(x, w, b, activation=activation, bm=bm, bn=bn, bk=bk,
-                       interpret=not _on_tpu())
+                       interpret=interpret)

@@ -2,24 +2,13 @@
 
 Defined as functions (not module constants) so importing never touches
 jax device state — the dry-run must set XLA_FLAGS before first jax init.
-
-``AxisType`` only exists in newer jax; on older installs we fall back to
-plain meshes (every axis defaults to Auto there anyway)."""
+Every axis is ``AxisType.Auto``: the sharding rules place arrays, and XLA
+propagates the rest."""
 
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:            # older jax: no explicit axis types
-    AxisType = None
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,7 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = 512 chips ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -35,4 +24,4 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import model as M
 
@@ -115,6 +116,7 @@ def main() -> None:
     ap.add_argument("--sample", action="store_true",
                     help="softmax-sample instead of greedy argmax")
     args = ap.parse_args()
+    enable_compile_cache()
     serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
           gen=args.gen, cache_len=args.cache_len, greedy=not args.sample)
 

@@ -1,10 +1,12 @@
-"""Production training runner: journal + checkpoint + watchdog + elastic
-remesh, over any assigned arch.
+"""Production training runner: journal + checkpoint + resume, over any
+assigned arch.
 
-On this CPU container it runs reduced configs end-to-end (the examples
-and tests use it); on a real pod the same runner drives the full configs —
-the step function and shardings are identical to the dry-run's
-(launch/steps.build_cell is the shared source of truth).
+The CPU tests and examples run reduced configs; on TPU chips the same
+runner drives the full configs. Params, optimizer state and batches are
+placed with the shardings ``launch/steps.build_cell`` computes for the
+mesh, and the step is jitted with them — the same source of truth the
+dry-run compiles. A step is pure in its inputs and donates the old
+params/state, so it is never re-run: a failed step fails the run.
 
 Usage::
 
@@ -17,34 +19,37 @@ from __future__ import annotations
 import argparse
 import os
 import time
+from dataclasses import replace
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpoint.checkpoint import load_checkpoint, save_checkpoint
 from repro.checkpoint.journal import TrainJournal
-from repro.configs.base import get_config
+from repro.configs.base import SHAPES, get_config
 from repro.data.pipeline import PipelineConfig, TokenPipeline
 from repro.distributed import sharding as shd
-from repro.distributed.watchdog import StepWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import make_train_step, rules_for
+from repro.launch.steps import build_cell
 from repro.models import model as M
 from repro.optim.optimizer import OptConfig, init_opt_state
 
 
 def train(arch: str, *, reduced: bool = True, steps: int = 20, batch: int = 8,
           seq: int = 64, ckpt_dir: str = "runs", ckpt_every: int = 10,
-          model_axis: int = 1, resume: bool = True, seed: int = 0,
+          model_axis: int = 1, mesh=None, resume: bool = True, seed: int = 0,
           data_mode: str = "cyclic", opt: OptConfig | None = None,
           log=print) -> dict:
+    """Train ``steps`` steps on ``mesh`` (default: every device of this
+    host, ``model_axis`` wide on "model")."""
     cfg = get_config(arch, reduced=reduced)
     opt = opt or OptConfig(peak_lr=1e-3, warmup_steps=5, decay_steps=steps,
                            weight_decay=0.0)
-    mesh = make_host_mesh(model=model_axis)
-    from repro.configs.base import SHAPES
-    rules = rules_for(cfg, SHAPES["train_4k"])   # same table as the dry-run
+    if mesh is None:
+        mesh = make_host_mesh(model=model_axis)
+    cell = build_cell(cfg, replace(SHAPES["train_4k"], seq=seq, batch=batch),
+                      mesh, opt)
+    params_sh, opt_sh, batch_sh = cell.in_shardings
 
     run_dir = os.path.join(ckpt_dir, f"{arch}{'_reduced' if reduced else ''}")
     os.makedirs(run_dir, exist_ok=True)
@@ -55,8 +60,10 @@ def train(arch: str, *, reduced: bool = True, steps: int = 20, batch: int = 8,
         n_codebooks=cfg.n_codebooks if cfg.frontend == "codebooks" else 0,
         embed_dim=cfg.d_model if cfg.frontend == "embeds" else 0))
 
-    params = M.init_params(cfg, jax.random.PRNGKey(seed))
-    opt_state = init_opt_state(params, opt)
+    params = jax.jit(lambda k: M.init_params(cfg, k),
+                     out_shardings=params_sh)(jax.random.PRNGKey(seed))
+    opt_state = jax.jit(lambda p: init_opt_state(p, opt),
+                        out_shardings=opt_sh)(params)
     start_step = 0
 
     # --- recovery: journal replay → (step cursor, checkpoint) -------------
@@ -64,22 +71,22 @@ def train(arch: str, *, reduced: bool = True, steps: int = 20, batch: int = 8,
     if last is not None:
         ck = last.get("ckpt")
         if ck and os.path.exists(os.path.join(ck, "manifest.json")):
-            _, params, opt_state = load_checkpoint(ck, params, opt_state)
+            _, params, opt_state = load_checkpoint(
+                ck, params, opt_state,
+                shardings={"params": params_sh, "opt": opt_sh})
         start_step = int(last["step"]) + 1
         log(f"[recover] resume at step {start_step} "
             f"(journal: {last['step']}, ckpt: {ck})")
 
-    step_fn = make_train_step(cfg, opt)
-    jitted = jax.jit(step_fn, donate_argnums=(0, 1))
-    watchdog = StepWatchdog()
+    jitted = jax.jit(cell.step, in_shardings=cell.in_shardings,
+                     out_shardings=cell.out_shardings,
+                     donate_argnums=cell.donate_argnums)
     losses = []
     t0 = time.time()
-    with shd.use_rules(rules, mesh):
+    with shd.use_rules(cell.rules, mesh):
         for step in range(start_step, steps):
-            batch_np = pipe.batch_at(step)
-            batch_j = {k: jnp.asarray(v) for k, v in batch_np.items()}
-            params, opt_state, metrics = watchdog.run(
-                jitted, params, opt_state, batch_j)
+            batch_j = jax.device_put(pipe.batch_at(step), batch_sh)
+            params, opt_state, metrics = jitted(params, opt_state, batch_j)
             loss = float(metrics["loss"])
             losses.append(loss)
             ckpt = None
@@ -93,9 +100,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 20, batch: int = 8,
                 f"lr {float(metrics['lr']):.2e} "
                 f"gnorm {float(metrics['grad_norm']):.3f}")
     return {"losses": losses, "params": params, "opt_state": opt_state,
-            "wall": time.time() - t0, "start_step": start_step,
-            "watchdog": {"timeouts": watchdog.timeouts_fired,
-                         "retries": watchdog.retries_used}}
+            "wall": time.time() - t0, "start_step": start_step}
 
 
 def main() -> None:
@@ -110,6 +115,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--no-resume", dest="resume", action="store_false")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(args.arch, reduced=args.reduced, steps=args.steps,
                 batch=args.batch, seq=args.seq, model_axis=args.model_axis,
                 ckpt_every=args.ckpt_every, resume=args.resume)

@@ -23,6 +23,13 @@ The op closes over the jitted grad function and the data pipeline, so it
 registers in a **program-private** registry chained to the global one —
 two concurrent programs never collide.
 
+Everything the program puts into the space is host (numpy) data: the
+space may live in a server process that must never touch the device
+(unpickling a ``jax.Array`` would ``device_put`` there). Only the
+process running the op and the combine moves arrays to the device: each
+handler uploads a param version once (``_device_params``), and the
+combine averages and applies the update with one jitted call.
+
 TS data-plane keys: ``("params", step)`` (current param tree),
 ``("gpart", step, micro)`` ((loss, grad-tree) per microbatch) — scoped
 to the ``jax_sgd`` namespace when co-resident with other programs on a
@@ -99,7 +106,21 @@ class JAXSGDProgram(WorkloadProgram):
         def loss_fn(params, batch):
             return M.train_loss(params, cfg, batch)[0]
 
-        self._grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+        #: ``(params, batch) -> (loss, grads)`` — what every op runs.
+        self.grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+
+        def sgd_update(params, grads_list):
+            def leaf(p, *gs):
+                g = sum(x.astype(jnp.float32) for x in gs) / len(gs)
+                return (p.astype(jnp.float32) - lr * g).astype(p.dtype)
+            return jax.tree.map(leaf, params, *grads_list)
+
+        #: ``(params, [grads per micro]) -> params``: mean-of-grads SGD,
+        #: accumulated in float32 — what every combine runs.
+        self.sgd_update = jax.jit(sgd_update)
+        # (version, device copy) of the last param version an op read.
+        self._dev_params: tuple[int, object] | None = None
+        self._dev_lock = threading.Lock()
         self.registry = OpRegistry(parent=ensure_builtin_ops())
         self.registry.register(OpSpec(
             JAXGRAD, self._grad_parts,
@@ -108,9 +129,20 @@ class JAXSGDProgram(WorkloadProgram):
 
     # ---------------------------------------------------------------- setup
     def setup(self, ts) -> None:
+        with self._dev_lock:
+            self._dev_params = None
         if ts.try_read(("params", ANY)) is None:
             params = M.init_params(self.cfg, jax.random.PRNGKey(self.seed))
-            ts.put(("params", 0), params)
+            ts.put(("params", 0), jax.device_get(params))
+
+    def _device_params(self, version: int, host_params):
+        """The device copy of param ``version``, uploaded once per version
+        for all handler threads (a version is immutable once committed)."""
+        with self._dev_lock:
+            if self._dev_params is None or self._dev_params[0] != version:
+                self._dev_params = None          # free the old copy first
+                self._dev_params = (version, jax.device_put(host_params))
+            return self._dev_params[1]
 
     # ---------------------------------------------------------- stage graph
     def n_rounds(self) -> int:
@@ -136,7 +168,7 @@ class JAXSGDProgram(WorkloadProgram):
         hit = ctx.ts.try_read(("params", ANY))
         if hit is None:
             raise PreconditionUnmet("params")
-        params = hit[1]
+        params = None
         items = []
         for t in tasks:
             with self._crash_lock:
@@ -148,10 +180,11 @@ class JAXSGDProgram(WorkloadProgram):
                 # discarded with nothing written, and the Manager's
                 # timeout re-issues it (paper §5.1).
                 raise PreconditionUnmet("injected handler crash")
+            if params is None:
+                params = self._device_params(hit[0][1], hit[1])
             micro = t.out_lo
-            batch = {k: jnp.asarray(v) for k, v in
-                     self.pipe.batch_at(t.step * self.n_micro + micro).items()}
-            loss, grads = self._grad_fn(params, batch)
+            batch = self.pipe.batch_at(t.step * self.n_micro + micro)
+            loss, grads = self.grad_fn(params, batch)
             items.append((("gpart", t.step, micro),
                           (float(loss), jax.device_get(grads))))
         return items
@@ -168,10 +201,8 @@ class JAXSGDProgram(WorkloadProgram):
             return                       # stage incomplete (stopped early)
         parts = [p[1] for p in parts]
         mean_loss = float(np.mean([p[0] for p in parts]))
-        grads = jax.tree.map(
-            lambda *gs: np.mean(np.stack(gs), axis=0), *[p[1] for p in parts])
-        new_params = jax.tree.map(
-            lambda p, g: (p - self.lr * g).astype(p.dtype), hit[1], grads)
+        new_params = jax.device_get(
+            self.sgd_update(hit[1], [p[1] for p in parts]))
         record_loss(ts, rnd, mean_loss, mgr.cfg.history_limit)
         if mgr.window.commit(0, rnd):    # §5.4 exactly-once
             ts.put(("params", rnd + 1), new_params)

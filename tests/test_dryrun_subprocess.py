@@ -25,22 +25,16 @@ from repro.launch.steps import build_cell, lower_cell
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.optim.optimizer import OptConfig
 
-try:
-    from jax.sharding import AxisType
-    _mesh_kw = {"axis_types": (AxisType.Auto,) * 2}
-except ImportError:          # older jax: no explicit axis types
-    _mesh_kw = {}
-mesh = jax.make_mesh((4, 2), ("data", "model"), **_mesh_kw)
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = get_config("deepseek_v2_lite_16b", reduced=True)
 shape = replace(SHAPES["train_4k"], seq=64, batch=8)
 cell = build_cell(cfg, shape, mesh, OptConfig())
 compiled = lower_cell(cell).compile()
 census = analyze_hlo(compiled.as_text(), total_devices=8)
 ma = compiled.memory_analysis()
-peak = getattr(ma, "peak_memory_in_bytes", None)
-if peak is None:    # older jax: no peak stat; conservative lower bound
-    peak = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
-            + ma.output_size_in_bytes)
+peak = ma.peak_memory_in_bytes
 out = {
     "flops": census.flops,
     "bytes": census.hbm_bytes,

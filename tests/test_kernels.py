@@ -1,15 +1,19 @@
 """Per-kernel allclose vs pure-jnp oracles, with hypothesis shape/dtype
-sweeps — all in interpret mode (TPU is the target, CPU validates)."""
+sweeps — all with ``interpret=True`` (the kernels compile for the TPU by
+default; the Pallas interpreter validates them here on the CPU). The
+TPU compiles themselves are in test_tpu_compile.py."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.tile_matmul.ops import matmul
 from repro.kernels.tile_matmul.ref import tile_matmul_ref
 from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.ops import attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.ssd_scan.ops import ssd
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
@@ -35,7 +39,8 @@ def test_tile_matmul_sweep(m, n, k, act, dtype, bias):
     x = jax.random.normal(k1, (m, k), jnp.float32).astype(dtype)
     w = jax.random.normal(k2, (k, n), jnp.float32).astype(dtype) * 0.1
     b = jax.random.normal(k3, (n,), jnp.float32).astype(dtype) if bias else None
-    out = matmul(x, w, b, activation=act, bm=128, bn=64, bk=128)
+    out = matmul(x, w, b, activation=act, bm=128, bn=64, bk=128,
+                 interpret=True)
     ref = tile_matmul_ref(x, w, b, activation=act)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -45,7 +50,7 @@ def test_tile_matmul_accumulates_over_k_blocks():
     # K split across 4 blocks — accumulation across grid steps must be exact
     x = jnp.ones((16, 512), jnp.float32)
     w = jnp.ones((512, 16), jnp.float32)
-    out = matmul(x, w, bm=16, bn=16, bk=128)
+    out = matmul(x, w, bm=16, bn=16, bk=128, interpret=True)
     np.testing.assert_allclose(out, np.full((16, 16), 512.0), rtol=1e-6)
 
 
@@ -109,6 +114,9 @@ def test_flash_matches_model_attention():
     kern = kern.reshape(B, Hkv, G, T, D).transpose(0, 3, 1, 2, 4)
     kern = kern.reshape(B, T, Hq, D)
     np.testing.assert_allclose(model_out, kern, rtol=2e-4, atol=2e-4)
+    # The (B, T, H, D) wrapper folds the same grouping itself.
+    wrapped = attention(q, k, v, bq=32, bk=32, interpret=True)
+    np.testing.assert_allclose(model_out, wrapped, rtol=2e-4, atol=2e-4)
 
 
 # ------------------------------------------------------------------- ssd
@@ -130,7 +138,7 @@ def test_ssd_sweep(bt, t, h, p, g, n, chunk):
     B = jax.random.normal(ks[3], (bt, t, g, n), jnp.float32) * 0.5
     C = jax.random.normal(ks[4], (bt, t, g, n), jnp.float32) * 0.5
     D = jnp.ones((h,))
-    y, s = ssd(x, dt, A, B, C, D, chunk=chunk)
+    y, s = ssd(x, dt, A, B, C, D, chunk=chunk, interpret=True)
     rep = h // g
     Bh = jnp.repeat(B, rep, axis=2).transpose(0, 2, 1, 3).reshape(bt * h, t, n)
     Ch = jnp.repeat(C, rep, axis=2).transpose(0, 2, 1, 3).reshape(bt * h, t, n)

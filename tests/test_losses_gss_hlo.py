@@ -3,7 +3,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.gss import PouchController, TimeoutController, gss_chunk
 from repro.models.losses import chunked_softmax_xent, multi_head_xent

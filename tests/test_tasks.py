@@ -7,7 +7,8 @@ task's (input × output) rectangle with disjoint pieces, (c) follow the
 4-way / 2-way split rules, (d) round-trip the declarative wire format."""
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (GLOBAL_OPS, LayerSpec, TaskDesc, UnknownOp,
                         partition, prototype_tasks)

@@ -11,7 +11,8 @@ import threading
 import time
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import ANY, Ledger, TSTimeout, TupleSpace, match
 from repro.core.space import (InstrumentedBackend, LocalBackend,
