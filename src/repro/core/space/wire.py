@@ -19,10 +19,10 @@ buffer and reconstructs arrays over zero-copy ``memoryview`` slices of
 it (``pickle.loads(..., buffers=...)``), so a weight tensor crosses the
 wire with exactly one copy end to end (the kernel socket transfer).
 
-Device arrays (``jax.Array``) are refused: decoding one calls
-``device_put``, which would make a host-only process (the tuple-space
-server, a handler worker) claim the accelerator its parent holds. Put
-host (numpy) arrays instead.
+Put host (numpy) arrays, not device arrays (``jax.Array``): decoding
+one calls ``device_put``, which would make a host-only process (the
+tuple-space server, a handler worker) claim the accelerator its parent
+holds.
 
 The framing is transport-agnostic: anything with ``sendmsg``/
 ``recv_into`` works (tests drive it over ``socket.socketpair`` with
@@ -31,11 +31,9 @@ deliberately fragmented writes to exercise partial-read recovery).
 
 from __future__ import annotations
 
-import io
 import os
 import pickle
 import struct
-import sys
 from typing import Any
 
 __all__ = ["FrameError", "IOV_MAX", "MAX_FRAME", "decode_msg",
@@ -71,25 +69,6 @@ class FrameError(ConnectionError):
     """Malformed frame (bad length prefix / truncated header)."""
 
 
-class _HostPickler(pickle.Pickler):
-    """Protocol-5 pickler that refuses device arrays (module docstring)."""
-
-    def reducer_override(self, obj):
-        jax = sys.modules.get("jax")     # no jax loaded -> no jax.Array
-        if jax is not None and isinstance(obj, jax.Array):
-            raise TypeError(
-                "a jax.Array cannot cross the tuple-space wire (the "
-                "receiving process would device_put it): put host "
-                "(numpy) arrays, e.g. jax.device_get(tree)")
-        return NotImplemented
-
-
-def _dumps(msg: Any, buffer_callback=None) -> bytes:
-    f = io.BytesIO()
-    _HostPickler(f, protocol=5, buffer_callback=buffer_callback).dump(msg)
-    return f.getvalue()
-
-
 def encode_segments(msg: Any) -> list[Any]:
     """Encode ``msg`` into the frame's segment list (bytes/memoryviews),
     ready for a gather write. Array bodies are referenced, not copied."""
@@ -99,12 +78,12 @@ def encode_segments(msg: Any) -> list[Any]:
         raw.append(pb.raw())              # flat view, zero-copy
 
     try:
-        pk = _dumps(msg, buffer_callback=_grab)
+        pk = pickle.dumps(msg, protocol=5, buffer_callback=_grab)
     except BufferError:
         # A non-contiguous buffer slipped through: fall back to in-band
         # pickling for the whole message (correct, just not zero-copy).
         raw = []
-        pk = _dumps(msg)
+        pk = pickle.dumps(msg, protocol=5)
     header = (_HDR.pack(len(raw), len(pk))
               + b"".join(_BUF.pack(len(r)) for r in raw))
     body_len = len(header) + len(pk) + sum(len(r) for r in raw)

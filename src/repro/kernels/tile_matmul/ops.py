@@ -1,5 +1,5 @@
 """Jitted public wrapper for tile_matmul: picks (8, 128)-legal block
-sizes and refuses shapes whose blocks would not fit VMEM."""
+sizes. Blocks that do not fit VMEM are refused by the Mosaic compiler."""
 
 from __future__ import annotations
 
@@ -9,18 +9,6 @@ import jax
 
 from repro.kernels.tile_matmul.kernel import tile_matmul
 from repro.kernels.tiling import LANE, SUBLANE, pick_block
-
-#: Default scoped-VMEM limit of a v5e TensorCore (16 MiB): what one
-#: kernel's double-buffered blocks plus scratch may occupy.
-VMEM_BUDGET = 16 * 2**20
-
-
-def _block_bytes(bm: int, bn: int, bk: int, itemsize: int,
-                out_itemsize: int) -> int:
-    """VMEM one grid step holds: double-buffered x/w/bias/out blocks plus
-    the fp32 accumulator."""
-    return (2 * (bm * bk + bk * bn + bn) * itemsize
-            + 2 * bm * bn * out_itemsize + bm * bn * 4)
 
 
 @partial(jax.jit, static_argnames=("activation", "bm", "bn", "bk",
@@ -35,11 +23,5 @@ def matmul(x, w, b=None, *, activation: str = "none", bm: int = 256,
     # bm is a sublane dim (x, out); bn and bk are lane dims (w/out, x).
     bm, bn, bk = (pick_block(M, bm, SUBLANE), pick_block(N, bn, LANE),
                   pick_block(K, bk, LANE))
-    need = _block_bytes(bm, bn, bk, x.dtype.itemsize, x.dtype.itemsize)
-    if need > VMEM_BUDGET:
-        raise ValueError(
-            f"tile_matmul cannot tile ({M}, {K}) @ ({K}, {N}): blocks "
-            f"(bm={bm}, bn={bn}, bk={bk}) need {need} B of VMEM, over "
-            f"{VMEM_BUDGET}")
     return tile_matmul(x, w, b, activation=activation, bm=bm, bn=bn, bk=bk,
                        interpret=interpret)

@@ -1,14 +1,56 @@
-"""Fault-tolerance substrates at the pjit layer: elastic re-mesh planning
-+ resharding, the ACAN-over-JAX step runner under crashes, and
-journal-based train resume."""
+"""Fault-tolerance substrates at the pjit layer: step watchdog
+(timeout/retransmission), elastic re-mesh planning + resharding, the
+ACAN-over-JAX step runner under crashes, and journal-based train resume."""
+
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.core.gss import TimeoutController
 from repro.distributed.elastic import DevicePool, plan_mesh, reshard_tree
+from repro.distributed.watchdog import StepTimeout, StepWatchdog
 from repro.distributed import sharding as shd
 from repro.models.common import ParamSpec
+
+
+# ------------------------------------------------------------- watchdog
+def test_watchdog_passthrough_and_adapt():
+    wd = StepWatchdog(controller=TimeoutController(timeout=2.0))
+    out = wd.run(lambda x: x + 1, 41)
+    assert out == 42
+    assert wd.timeouts_fired == 0
+    # healthy steps shrink the timeout toward latency × slack
+    for _ in range(5):
+        wd.run(lambda: time.sleep(0.01))
+    assert wd.controller.timeout < 2.0
+
+
+def test_watchdog_reissues_straggler():
+    wd = StepWatchdog(controller=TimeoutController(timeout=0.1,
+                                                   min_timeout=0.05),
+                      max_retries=3)
+    calls = []
+
+    def flaky():
+        calls.append(1)
+        if len(calls) == 1:
+            time.sleep(0.5)       # straggler on first attempt
+        return "ok"
+
+    assert wd.run(flaky) == "ok"
+    assert wd.timeouts_fired == 1
+    assert len(calls) >= 2        # re-issued — the paper's retransmission
+
+
+def test_watchdog_gives_up():
+    wd = StepWatchdog(controller=TimeoutController(timeout=0.05,
+                                                   min_timeout=0.01),
+                      max_retries=1)
+    with pytest.raises(StepTimeout):
+        wd.run(lambda: time.sleep(2.0))
 
 
 # ------------------------------------------------------------- elastic

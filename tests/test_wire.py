@@ -256,12 +256,3 @@ def test_concurrent_senders_interleave_whole_frames():
 
 def test_any_pickles_to_singleton():
     assert pickle.loads(pickle.dumps(ANY)) is ANY
-
-
-def test_device_arrays_are_refused():
-    """A jax.Array would be device_put by whichever process decodes it
-    (the tuple-space server, a worker): the wire refuses it outright."""
-    import jax.numpy as jnp
-    with pytest.raises(TypeError, match="jax.Array"):
-        encode_segments(("params", {"w": jnp.zeros(4)}))
-    encode_segments(("params", {"w": np.zeros(4)}))      # host arrays pass
