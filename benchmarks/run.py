@@ -10,9 +10,7 @@ where the row carries one).
 
 Paper experiments (§6, Figures 1-4) run at CI scale by default (compressed
 intervals, smaller N — structure preserved: speed ratios 1:5:10, crash
-probability 1.0); ``--paper-scale`` runs the exact paper setup (slower).
-The roofline rows summarise the multi-pod dry-run artifacts if present
-(see launch/dryrun.py)."""
+probability 1.0); ``--paper-scale`` runs the exact paper setup (slower)."""
 
 from __future__ import annotations
 
@@ -135,21 +133,6 @@ def main() -> None:
     rows.extend(KB.bench_tile_matmul())
     rows.extend(KB.bench_attention())
     rows.extend(KB.bench_ssd())
-
-    # Roofline summary from dry-run artifacts (if the sweep has been run)
-    try:
-        from benchmarks.roofline import load_cells, roofline_fraction, summary
-        cells = load_cells()
-        if cells:
-            s = summary(cells)
-            rows.append(("dryrun_roofline_cells", 0.0,
-                         f"n={s['cells']} dominant={s['dominant_histogram']}"))
-            for c in cells:
-                rows.append((f"roofline_{c['arch']}_{c['shape']}", 0.0,
-                             f"dom={c['dominant'].replace('_s','')} "
-                             f"frac={roofline_fraction(c):.3f}"))
-    except Exception as e:              # noqa: BLE001
-        rows.append(("dryrun_roofline_cells", 0.0, f"unavailable: {e}"))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -149,6 +149,10 @@ class CloudConfig:
                     f"handler_speeds must be > 0, got {self.handler_speeds}")
 
 
+#: Manager counters that a revival must not reset: banked per program.
+MANAGER_COUNTERS = ("reissued", "timed_out_tasks")
+
+
 @dataclass
 class CloudResult:
     loss_history: list          # [(step, loss)]
@@ -184,6 +188,9 @@ class CloudResult:
     #: Tasks re-published after a barrier timeout, summed over every
     #: Manager incarnation of this program.
     reissues: int = 0
+    #: Tasks still pending when a GSS deadline fired (the cause of
+    #: ``reissues``), summed the same way.
+    timed_out_tasks: int = 0
 
 
 @dataclass
@@ -315,7 +322,8 @@ class ACANCloud:
         # object, and the cost_report surface must read the live model.
         old = self._managers[i]
         if old is not None:
-            self._reissued_retired[i] += old.reissued
+            for k in MANAGER_COUNTERS:
+                self._retired[i][k] += getattr(old, k)
         self._managers[i] = mgr
         suffix = f"-{self.namespaces[i]}" if self.multi else ""
         th = threading.Thread(target=self._role_body, args=(mgr.run,),
@@ -484,10 +492,16 @@ class ACANCloud:
             race_report=([] if raced is None
                          else raced.race_report(self.namespaces[i])),
             finished=self._finished(i),
-            reissues=self._reissued_retired[i] + (
-                self._managers[i].reissued
-                if self._managers[i] is not None else 0),
+            reissues=self._manager_count(i, "reissued"),
+            timed_out_tasks=self._manager_count(i, "timed_out_tasks"),
         )
+
+    def _manager_count(self, i: int, name: str) -> int:
+        """Manager counter ``name`` of program ``i``, summed over every
+        incarnation (the retired ones and the live one)."""
+        mgr = self._managers[i]
+        return self._retired[i][name] + (
+            getattr(mgr, name) if mgr is not None else 0)
 
     def _wait_finished(self, deadline: float) -> None:
         """Block until every Manager published its finished flag, the
@@ -539,7 +553,8 @@ class ACANCloud:
         self._speed_boxes = [SpeedBox(float(s)) for s in speeds]
         self._handlers: list[Handler | None] = [None] * cfg.n_handlers
         self._managers: list[Manager | None] = [None] * n_programs
-        self._reissued_retired = [0] * n_programs
+        self._retired = [dict.fromkeys(MANAGER_COUNTERS, 0)
+                         for _ in range(n_programs)]
         self._busy_retired = 0.0
         self._failures: list[tuple[str, BaseException | None]] = []
         self._fail_lock = threading.Lock()

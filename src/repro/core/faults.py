@@ -21,6 +21,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro.core.trace import instant
+
 
 @dataclass
 class FaultPlan:
@@ -92,7 +94,6 @@ class MonitorDaemon:
     handler_crash_firings: int = 0
     crashpoint_firings: int = 0
     speed_changes: int = 0
-    power_log: list = field(default_factory=list)
 
     def __post_init__(self) -> None:
         self._rng = np.random.default_rng(self.plan.seed)
@@ -179,14 +180,18 @@ class MonitorDaemon:
             for box in self.speed_boxes:
                 box.set(float(rng.choice(self.plan.speed_levels)))
             self.speed_changes += 1
-        if rng.random() < self.plan.p_manager_crash:
+        managers = rng.random() < self.plan.p_manager_crash
+        if managers:
             for i, ev in enumerate(self.manager_crashes):
                 if self._tenant_plans[i] is None:
                     ev.set()
                     self.manager_crash_firings_by[i] += 1
-        if rng.random() < self.plan.p_handler_crash:
+        handlers = rng.random() < self.plan.p_handler_crash
+        if handlers:
             for ev in self.handler_crashes:
                 ev.set()
+        instant("acan.fault.fire", manager=int(managers),
+                handlers=int(handlers))
 
     def _fire_tenant_faults(self, i: int) -> None:
         """One firing of tenant ``i``'s own plan (manager-crash axis
@@ -225,11 +230,13 @@ class MonitorDaemon:
                 # a crash — revive it from its TS cursor (paper §6:
                 # "revives failed Manager thread using the latest
                 # checkpoint").
+                instant("acan.fault.revive", role="manager", index=i)
                 self._mthreads[i] = self.make_manager_threads(i)
                 self.manager_revivals += 1
                 self.manager_revivals_by[i] += 1
         for i, th in enumerate(self._hthreads):
             if th is not None and not th.is_alive():
+                instant("acan.fault.revive", role="handler", index=i)
                 self._hthreads[i] = self.make_handler_thread(i)
                 self.handler_revivals += 1
 
@@ -286,5 +293,4 @@ class MonitorDaemon:
                     tenant_last[i] = now
             self._account_crashpoint()
             self._revive()
-            self.power_log.append((time.time(), self.power()))
         self._account_crashpoint()   # drain firings raced with stop

@@ -72,6 +72,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from contextlib import ExitStack
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -81,6 +82,7 @@ from repro.core.conflict import CommitWindow
 from repro.core.program import (FINISH_STAGE, UnknownOp, WorkloadProgram,
                                 effects_conflict)
 from repro.core.tasks import TaskDesc, content_key
+from repro.core.trace import instant, span
 from repro.core.space import (ANY, FieldIn, TSTimeout, TupleSpace,
                               find_raced, role, stage_context)
 
@@ -202,6 +204,7 @@ class Manager:
     cost_model: OnlineCostModel | None = None
     rounds: int = 0                  # pouch rounds (monotonic via TS)
     reissued: int = 0                # tasks re-published after a timeout
+    timed_out_tasks: int = 0         # tasks pending at fired deadlines
     epoch: int = 0                   # (re)start count, persisted in TS
     _task_seq: int = 0
 
@@ -227,6 +230,10 @@ class Manager:
         self._fence_warned: set[tuple[str, str]] = set()
         self._raced = None
         self._ns = ""
+        # The acan.manager.recover span: open from the start of run() to
+        # this incarnation's first issued pouch.
+        self._recovering = ExitStack()
+        self._recovery = None
 
     # ------------------------------------------------------------ lifecycle
     def _bump_epoch(self) -> None:
@@ -237,6 +244,8 @@ class Manager:
         self.epoch = (hit[1] if hit is not None else 0) + 1
         self.ts.delete(("mstate", "epoch"))
         self.ts.put(("mstate", "epoch"), self.epoch)
+        if self._recovery is not None:
+            self._recovery.set_metadata(epoch=self.epoch)
 
     def _checkpoint(self) -> None:
         """Persist the completed-stage frontier plus controller state.
@@ -573,6 +582,7 @@ class Manager:
                 run.units_left = 0.0
         pouch = pending[: self._pouch_size(pending)]
         run.tids.update(self._issue(pouch))
+        self._recovering.close()         # recovered: work is out again
         # Re-issues are tasks published a second time (timeout
         # stragglers) — NOT later pouches of a stage wider than
         # pouch_size, whose tasks are being published for the first time.
@@ -605,6 +615,13 @@ class Manager:
             still: list[TaskDesc] = []
         else:
             still = self._scan_pending(run.pouch, run.done_pat)
+        if still:
+            # The GSS deadline fired with tasks of the pouch pending: the
+            # cause of every straggler re-issue.
+            self.timed_out_tasks += len(still)
+            instant("acan.manager.gss_timeout", rnd=run.rnd,
+                    epoch=self.epoch, pending=len(still),
+                    issued=len(run.pouch))
         done_frac = 1.0 - len(still) / max(len(run.pouch), 1)
         self.controller.update(not still, elapsed, done_frac)
         if self.cfg.adaptive_pouch:
@@ -806,7 +823,9 @@ class Manager:
         # The role tag is thread-local; Manager.run() may execute on a
         # borrowed thread (step_runner drives it on the caller's), so the
         # context manager form restores whatever role that thread had.
-        with role("manager"):
+        with role("manager"), self._recovering:
+            self._recovery = self._recovering.enter_context(
+                span("acan.manager.recover"))
             self._run()
 
     def _run(self) -> None:

@@ -30,6 +30,9 @@ process running the op and the combine moves arrays to the device: each
 handler uploads a param version once (``_device_params``), and the
 combine averages and applies the update with one jitted call.
 
+Each move between host and device is a span of its own (``acan.jax_sgd.*``,
+:mod:`repro.core.trace`) that carries the ``bytes`` it moved.
+
 TS data-plane keys: ``("params", step)`` (current param tree),
 ``("gpart", step, micro)`` ((loss, grad-tree) per microbatch) — scoped
 to the ``jax_sgd`` namespace when co-resident with other programs on a
@@ -54,10 +57,17 @@ from repro.core.program import (FINISH_STAGE, OpRegistry, OpSpec,
 from repro.core.space import ANY
 from repro.core.space.schema import KeySchema, int_field
 from repro.core.tasks import TaskDesc
+from repro.core.trace import span
 from repro.data.pipeline import PipelineConfig, TokenPipeline
 from repro.models import model as M
 
 JAXGRAD = "jaxgrad"
+
+
+def nbytes(tree) -> int:
+    """The summed ``nbytes`` of ``tree``'s leaves."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
 
 # Declared data-plane key protocol (PR 6). ("params", steps) — the final
 # committed version — intentionally survives shutdown: persistent.
@@ -137,11 +147,17 @@ class JAXSGDProgram(WorkloadProgram):
 
     def _device_params(self, version: int, host_params):
         """The device copy of param ``version``, uploaded once per version
-        for all handler threads (a version is immutable once committed)."""
-        with self._dev_lock:
+        for all handler threads (a version is immutable once committed).
+        The span holds the wait for the lock and, for a new version, the
+        upload's dispatch; the grad's compute span waits for the rest."""
+        with span("acan.jax_sgd.grad.params_upload",
+                  version=version) as sp, self._dev_lock:
             if self._dev_params is None or self._dev_params[0] != version:
                 self._dev_params = None          # free the old copy first
                 self._dev_params = (version, jax.device_put(host_params))
+                sp.set_metadata(uploaded=1, bytes=nbytes(host_params))
+            else:
+                sp.set_metadata(uploaded=0)
             return self._dev_params[1]
 
     # ---------------------------------------------------------- stage graph
@@ -180,33 +196,52 @@ class JAXSGDProgram(WorkloadProgram):
                 # discarded with nothing written, and the Manager's
                 # timeout re-issues it (paper §5.1).
                 raise PreconditionUnmet("injected handler crash")
-            if params is None:
-                params = self._device_params(hit[0][1], hit[1])
             micro = t.out_lo
-            batch = self.pipe.batch_at(t.step * self.n_micro + micro)
-            loss, grads = self.grad_fn(params, batch)
-            items.append((("gpart", t.step, micro),
-                          (float(loss), jax.device_get(grads))))
+            with span("acan.jax_sgd.grad", step=t.step, micro=micro):
+                if params is None:
+                    params = self._device_params(hit[0][1], hit[1])
+                batch = self.pipe.batch_at(t.step * self.n_micro + micro)
+                # The batch is host data: the call uploads it.
+                with span("acan.jax_sgd.grad.compute", step=t.step,
+                          micro=micro, bytes=nbytes(batch)):
+                    out = jax.block_until_ready(self.grad_fn(params, batch))
+                with span("acan.jax_sgd.grad.fetch", step=t.step,
+                          micro=micro) as sp:
+                    loss, grads = jax.device_get(out)
+                    sp.set_metadata(bytes=nbytes((loss, grads)))
+            items.append((("gpart", t.step, micro), (float(loss), grads)))
         return items
 
     # -------------------------------------------------------------- combine
     def combine(self, ts, rnd: int, stage: str, mgr) -> None:
-        if not mgr.window.can_commit(0, rnd):
-            return                       # already committed before a crash
-        hit = ts.try_read(("params", rnd))
-        if hit is None:
-            return
-        parts = [ts.try_read(("gpart", rnd, m)) for m in range(self.n_micro)]
-        if any(p is None for p in parts):
-            return                       # stage incomplete (stopped early)
-        parts = [p[1] for p in parts]
-        mean_loss = float(np.mean([p[0] for p in parts]))
-        new_params = jax.device_get(
-            self.sgd_update(hit[1], [p[1] for p in parts]))
-        record_loss(ts, rnd, mean_loss, mgr.cfg.history_limit)
-        if mgr.window.commit(0, rnd):    # §5.4 exactly-once
-            ts.put(("params", rnd + 1), new_params)
-            ts.delete(("params", rnd))
+        with span("acan.jax_sgd.combine", step=rnd):
+            if not mgr.window.can_commit(0, rnd):
+                return                   # already committed before a crash
+            with span("acan.jax_sgd.combine.gather", step=rnd):
+                hit = ts.try_read(("params", rnd))
+                parts = [] if hit is None else [
+                    ts.try_read(("gpart", rnd, m))
+                    for m in range(self.n_micro)]
+            if hit is None or any(p is None for p in parts):
+                return                   # stage incomplete (stopped early)
+            parts = [p[1] for p in parts]
+            mean_loss = float(np.mean([p[0] for p in parts]))
+            with span("acan.jax_sgd.combine.upload", step=rnd) as sp:
+                host = (hit[1], [p[1] for p in parts])
+                params, grads = jax.block_until_ready(jax.device_put(host))
+                sp.set_metadata(bytes=nbytes(host))
+            with span("acan.jax_sgd.combine.update", step=rnd):
+                new = jax.block_until_ready(self.sgd_update(params, grads))
+            del params, grads
+            with span("acan.jax_sgd.combine.fetch", step=rnd) as sp:
+                new_params = jax.device_get(new)
+                sp.set_metadata(bytes=nbytes(new_params))
+            del new
+            with span("acan.jax_sgd.combine.commit", step=rnd):
+                record_loss(ts, rnd, mean_loss, mgr.cfg.history_limit)
+                if mgr.window.commit(0, rnd):    # §5.4 exactly-once
+                    ts.put(("params", rnd + 1), new_params)
+                    ts.delete(("params", rnd))
 
     # -------------------------------------------------------------- cleanup
     def finish_round(self, ts, rnd: int) -> None:

@@ -185,8 +185,10 @@ def test_daemon_run_fires_on_interval_and_stops():
     assert not th.is_alive()
     assert d.speed_changes >= 2
     assert d.handler_revivals >= 1
-    assert len(d.power_log) > 0
-    assert all(np.isfinite(p) for _, p in d.power_log)
+    # Both handlers live (one revived), each at a speed of the plan.
+    assert np.isfinite(d.power())
+    assert {b.get() for b in d.speed_boxes} <= set(d.plan.speed_levels)
+    assert d.power() == sum(b.get() for b in d.speed_boxes)
 
 
 # ------------------------------------------------- per-tenant fault plans

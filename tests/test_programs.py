@@ -187,7 +187,10 @@ def test_reissued_counts_only_straggler_republications():
     stop.set()
     th.join(timeout=2.0)
     assert ts.try_read(("mstate", "finished")) is not None
-    assert mgr.reissued == 0, mgr.reissued
+    # A genuine GSS timeout under load may re-issue its pending tasks; a
+    # first-time pouch counted as a re-issue would exceed them.
+    assert mgr.reissued <= mgr.timed_out_tasks, (mgr.reissued,
+                                                 mgr.timed_out_tasks)
 
 
 def test_moe_respects_history_limit():
