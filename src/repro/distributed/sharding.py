@@ -129,6 +129,12 @@ def use_rules(rules: dict, mesh: Mesh):
         _CTX.v = prev
 
 
+def rules_active() -> bool:
+    """Whether a ``use_rules`` context is active: the program is being
+    traced for a mesh under GSPMD."""
+    return getattr(_CTX, "v", None) is not None
+
+
 def shard_act(x, logical_axes):
     """with_sharding_constraint against the active rules; no-op outside a
     ``use_rules`` context (single-device tests/examples)."""

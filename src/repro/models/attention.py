@@ -1,12 +1,22 @@
-"""Attention math: flash-style chunked attention (train/prefill) and dense
+"""Attention math: train/prefill attention, by one of two paths, and dense
 decode attention over a (possibly ring-buffered) KV cache.
 
-Memory discipline: train/prefill attention never materialises the full
-``(Tq, Tkv)`` score matrix — it runs an online-softmax over KV chunks inside
-a ``lax.scan``, with an outer ``lax.map`` over Q chunks. This is the same
-algorithm as the Pallas ``flash_attention`` kernel (``kernels/flash_attention``)
-— the jnp version here is both the oracle for the kernel and the path the
-multi-pod dry-run lowers (Pallas does not lower on the host platform).
+Train/prefill attention never materialises the full ``(Tq, Tkv)`` score
+matrix in HBM. :func:`gqa_attention` runs it by one of two paths, chosen
+by :func:`attention_impl` from what the call shows:
+
+- **flash** — on a single TPU chip (no ``use_rules`` mesh), causal from
+  position 0 with ``Dk == Dv``, no soft cap, no window shorter than the
+  sequence, and T on the kernel's block grid: the bundled Pallas splash
+  kernel (``jax.experimental.pallas.ops.tpu.splash_attention``), forward
+  and backward. It keeps each score tile in VMEM, skips the blocks the
+  causal mask empties, and reads each KV head once for its G query heads.
+- **chunked** — everywhere else (the CPU, the host dry-run, any run under
+  a mesh, MLA, ragged or offset shapes): :func:`chunked_attention`, an
+  online-softmax over KV chunks inside a ``lax.scan`` with an outer
+  ``lax.map`` over Q chunks, in pure jnp. Pallas does not lower on the
+  host platform, and a ``pallas_call`` under GSPMD would need a
+  ``shard_map``. It is also the oracle the flash path is tested against.
 
 Decode attention is written densely on purpose: with the cache sequence
 axis sharded over mesh axes, GSPMD turns the softmax + PV contraction into
@@ -16,13 +26,15 @@ automatically — see DESIGN.md §5.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard_act
+from repro.core import trace
+from repro.distributed.sharding import rules_active, shard_act
+from repro.kernels.tiling import LANE, pick_block
 from repro.models.common import soft_cap
 
 NEG_INF = -1e30
@@ -72,8 +84,8 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     model axis — measured on danube-1.8b, GSPMD then shards Hkv×G as 8×2
     and emits full-replication all-gathers of score-sized tensors inside
     the backward scan (EXPERIMENTS.md §Perf iterations 1-2). Flat heads
-    shard cleanly; the Pallas kernel keeps the grouped layout internally
-    where it belongs (per-KV-head HBM reuse on real hardware).
+    shard cleanly; the single-chip flash path (:func:`flash_attention`)
+    keeps the grouped layout, one KV head read for its G query heads.
 
     ``q_offset`` is the absolute position of q[0] relative to k[0]
     (chunked prefill / decode-prefill continuation support).
@@ -149,17 +161,107 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return out[:, :Tq_real]
 
 
+# ---------------------------------------------------------------------------
+# Flash attention (Pallas splash kernel; single-chip TPU train / prefill)
+# ---------------------------------------------------------------------------
+
+#: Largest q and kv block of the flash kernel, and largest slice a kv
+#: block is computed in. From a sweep of one smollm-360m grad call on a
+#: v5e (B 4, T 2048; PERF.md).
+FLASH_BLOCK = 1024
+FLASH_KV_COMPUTE = 512
+
+
+def flash_blocks(T: int) -> tuple[int, int]:
+    """The flash kernel's (block, kv compute slice) for a sequence of T:
+    each the largest whole number of lanes within its limit that divides
+    what it tiles, else all of it."""
+    block = pick_block(T, FLASH_BLOCK, LANE)
+    return block, pick_block(block, FLASH_KV_COMPUTE, LANE)
+
+
+def attention_impl(T: int, dk: int, dv: int, cfg: AttnCfg, q_offset: int,
+                   *, backend: str, meshed: bool) -> str:
+    """``"flash"`` or ``"chunked"``: the path :func:`gqa_attention` takes
+    for a causal self-attention over T positions with head widths dk/dv,
+    on ``backend`` (``jax.default_backend()``), under a ``use_rules``
+    mesh when ``meshed``."""
+    block, _ = flash_blocks(T)
+    if (backend == "tpu" and not meshed and q_offset == 0 and dk == dv
+            and cfg.softcap == 0 and (cfg.window == 0 or cfg.window >= T)
+            and block % LANE == 0):
+        return "flash"
+    return "chunked"
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(T: int, G: int, blocks: tuple[int, int],
+                   interpret: bool):
+    """The splash MQA kernel for G query heads over one KV head, causal
+    over T, with the fused backward; built once per shape."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+    mask = splash.MultiHeadMask([splash.CausalMask((T, T))] * G)
+    block, compute = blocks
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=compute,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True)
+    # The mask tables are arrays the kernel closes over: make them
+    # concrete, so that a kernel first built inside one trace serves
+    # every later one.
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            mask, block_sizes=sizes, interpret=interpret)
+
+
+def flash_attention(q, k, v, *, blocks: tuple[int, int],
+                    interpret: bool = False):
+    """Causal GQA attention by the Pallas splash kernel, forward and
+    backward (its ``custom_vjp``).
+
+    q: (B, T, Hq, D); k, v: (B, T, Hkv, D) → (B, T, Hq, D). Query head h
+    reads KV head h // G, as :func:`gqa_attention`'s repeat does. The
+    kernel is vmapped over batch and KV heads, so K and V are never
+    repeated; q is scaled by 1/sqrt(D) before the call. ``blocks`` is
+    the (q and kv block, kv compute slice), as :func:`flash_blocks`
+    gives. Products accumulate in f32; the kernel's forward takes P·V in
+    f32, every other product in the inputs' dtype."""
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    kernel = _splash_kernel(T, G, blocks, interpret)
+    q = q * jnp.asarray(D ** -0.5, q.dtype)
+    q = q.reshape(B, T, Hkv, G, D).transpose(0, 2, 3, 1, 4)
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
+    out = jax.vmap(jax.vmap(kernel))(q, k, v)          # (B, Hkv, G, T, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D)
+
+
 def gqa_attention(q, k, v, cfg: AttnCfg, *, q_offset: int = 0,
                   q_chunk: int = 512, kv_chunk: int = 512):
     """q: (B, T, Hq, Dk) → (B, T, Hq, Dv); k/v: (B, T, Hkv, D*).
 
-    KV heads are repeated to Hq (flat layout) — see chunked_attention's
-    docstring for why; the G× activation-memory cost is the price of a
-    clean head sharding on the jnp path (the Pallas kernel reuses KV
-    tiles natively instead)."""
+    Causal self-attention by the path :func:`attention_impl` picks; the
+    choice is recorded once per trace as an ``acan.model.attention``
+    instant (ids ``impl``, ``seq``, ``heads``).
+
+    - ``flash`` (single-chip TPU, see the module docstring):
+      :func:`flash_attention`, grouped heads; ``q_chunk``/``kv_chunk``
+      are unused (the kernel's blocks come from T alone).
+    - ``chunked``: KV heads are repeated to Hq (flat layout) and
+      :func:`chunked_attention` runs — see its docstring for why; the G×
+      activation-memory cost is the price of a clean head sharding under
+      a mesh."""
     B, T, Hq, Dk = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
+    impl = attention_impl(T, Dk, v.shape[-1], cfg, q_offset,
+                          backend=jax.default_backend(),
+                          meshed=rules_active())
+    trace.instant("acan.model.attention", impl=impl, seq=T, heads=Hq)
+    if impl == "flash":
+        return flash_attention(q, k, v, blocks=flash_blocks(T))
     if G > 1:
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)

@@ -34,8 +34,9 @@ Each move between host and device is a span of its own (``acan.jax_sgd.*``,
 :mod:`repro.core.trace`) that carries the ``bytes`` it moved.
 
 TS data-plane keys: ``("params", step)`` (current param tree),
-``("gpart", step, micro)`` ((loss, grad-tree) per microbatch) — scoped
-to the ``jax_sgd`` namespace when co-resident with other programs on a
+``("gpart", step, micro)`` ((loss, grad tree as the slices of
+:func:`slice_leaves`) per microbatch) — scoped to the ``jax_sgd``
+namespace when co-resident with other programs on a
 multi-tenant cloud (the op's ``ctx.ts`` is then that tenant's
 :class:`~repro.core.space.ScopedSpace`, so a handler fleet can serve
 JAX training next to the numpy programs on one space).
@@ -43,7 +44,9 @@ JAX training next to the numpy programs on one space).
 
 from __future__ import annotations
 
+import functools
 import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +72,47 @@ def nbytes(tree) -> int:
     return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
+#: Largest slice in which a gradient leaf crosses to the host. On a TPU
+#: v5e host, copies of whole leaves (up to 157 MB for smollm-360m) into
+#: fresh host buffers, three gradient trees at a time, used up host
+#: memory at ~2 GB/s once more than ~1.5 trees a second crossed; in
+#: slices of 16 MB the same traffic held host memory flat and a tree's
+#: copy took 0.38 s, not 0.47 s (PERF.md).
+FETCH_SLICE_BYTES = 16 << 20
+
+
+#: Most threads the program makes device calls from. The TPU runtime
+#: keeps ~0.45 GB of host memory for each thread that ever moved data to
+#: or from the device (a fresh thread per grad call, three at a time,
+#: used up host memory at ~1 GB/s; PERF.md), and the fault plane
+#: replaces every handler and Manager thread it kills. So the program
+#: makes its device calls from threads of its own that outlive them.
+DEVICE_THREADS = 8
+
+
+def slice_leaves(tree, limit: int) -> list[list]:
+    """Each leaf of ``tree`` as a list of slices along its first axis of
+    at most ``limit`` bytes (at least one row); a leaf within it, or a
+    scalar, as ``[leaf]``. The cuts follow from the shapes, so this runs
+    under ``jit``."""
+    out = []
+    for x in jax.tree.leaves(tree):
+        size = x.size * x.dtype.itemsize
+        if x.ndim == 0 or size <= limit:
+            out.append([x])
+            continue
+        rows = max(1, limit * x.shape[0] // size)
+        out.append([x[i:i + rows] for i in range(0, x.shape[0], rows)])
+    return out
+
+
+def join_leaves(treedef, slices: list[list]):
+    """Inverse of :func:`slice_leaves`: the tree of ``treedef`` whose
+    leaves are ``slices`` joined along their first axis."""
+    return jax.tree.unflatten(treedef, [
+        s[0] if len(s) == 1 else jnp.concatenate(s) for s in slices])
+
+
 # Declared data-plane key protocol (PR 6). ("params", steps) — the final
 # committed version — intentionally survives shutdown: persistent.
 KEY_SCHEMAS: tuple[KeySchema, ...] = (
@@ -83,7 +127,7 @@ KEY_SCHEMAS: tuple[KeySchema, ...] = (
               consumers=frozenset({"manager"}),
               deleters=frozenset({"manager", "handler"}),
               lifecycle="round_scoped",
-              description="(loss, grad tree) per microbatch"),
+              description="(loss, grad tree in slices) per microbatch"),
 )
 
 
@@ -118,6 +162,14 @@ class JAXSGDProgram(WorkloadProgram):
 
         #: ``(params, batch) -> (loss, grads)`` — what every op runs.
         self.grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+        # A gradient crosses to the host, and back for the combine, as
+        # the slices of slice_leaves (see FETCH_SLICE_BYTES).
+        self._slice = jax.jit(
+            functools.partial(slice_leaves, limit=FETCH_SLICE_BYTES))
+        self._join = jax.jit(join_leaves, static_argnums=0)
+        self._treedef = jax.tree.structure(M.abstract_params(cfg))
+        self._pool = ThreadPoolExecutor(DEVICE_THREADS,
+                                        thread_name_prefix="jax_sgd-device")
 
         def sgd_update(params, grads_list):
             def leaf(p, *gs):
@@ -145,6 +197,11 @@ class JAXSGDProgram(WorkloadProgram):
             params = M.init_params(self.cfg, jax.random.PRNGKey(self.seed))
             ts.put(("params", 0), jax.device_get(params))
 
+    def _on_device(self, fn, *args):
+        """``fn(*args)`` on one of the program's device threads (see
+        :data:`DEVICE_THREADS`); the caller waits for it."""
+        return self._pool.submit(fn, *args).result()
+
     def _device_params(self, version: int, host_params):
         """The device copy of param ``version``, uploaded once per version
         for all handler threads (a version is immutable once committed).
@@ -154,7 +211,8 @@ class JAXSGDProgram(WorkloadProgram):
                   version=version) as sp, self._dev_lock:
             if self._dev_params is None or self._dev_params[0] != version:
                 self._dev_params = None          # free the old copy first
-                self._dev_params = (version, jax.device_put(host_params))
+                self._dev_params = (version, self._on_device(
+                    jax.device_put, host_params))
                 sp.set_metadata(uploaded=1, bytes=nbytes(host_params))
             else:
                 sp.set_metadata(uploaded=0)
@@ -204,10 +262,12 @@ class JAXSGDProgram(WorkloadProgram):
                 # The batch is host data: the call uploads it.
                 with span("acan.jax_sgd.grad.compute", step=t.step,
                           micro=micro, bytes=nbytes(batch)):
-                    out = jax.block_until_ready(self.grad_fn(params, batch))
+                    out = self._on_device(lambda: jax.block_until_ready(
+                        self.grad_fn(params, batch)))
                 with span("acan.jax_sgd.grad.fetch", step=t.step,
                           micro=micro) as sp:
-                    loss, grads = jax.device_get(out)
+                    loss, grads = self._on_device(lambda: jax.device_get(
+                        (out[0], self._slice(out[1]))))
                     sp.set_metadata(bytes=nbytes((loss, grads)))
             items.append((("gpart", t.step, micro), (float(loss), grads)))
         return items
@@ -228,13 +288,14 @@ class JAXSGDProgram(WorkloadProgram):
             mean_loss = float(np.mean([p[0] for p in parts]))
             with span("acan.jax_sgd.combine.upload", step=rnd) as sp:
                 host = (hit[1], [p[1] for p in parts])
-                params, grads = jax.block_until_ready(jax.device_put(host))
+                params, grads = self._on_device(self._upload, host)
                 sp.set_metadata(bytes=nbytes(host))
             with span("acan.jax_sgd.combine.update", step=rnd):
-                new = jax.block_until_ready(self.sgd_update(params, grads))
+                new = self._on_device(lambda: jax.block_until_ready(
+                    self.sgd_update(params, grads)))
             del params, grads
             with span("acan.jax_sgd.combine.fetch", step=rnd) as sp:
-                new_params = jax.device_get(new)
+                new_params = self._on_device(jax.device_get, new)
                 sp.set_metadata(bytes=nbytes(new_params))
             del new
             with span("acan.jax_sgd.combine.commit", step=rnd):
@@ -242,6 +303,15 @@ class JAXSGDProgram(WorkloadProgram):
                 if mgr.window.commit(0, rnd):    # §5.4 exactly-once
                     ts.put(("params", rnd + 1), new_params)
                     ts.delete(("params", rnd))
+
+    def _upload(self, host):
+        """``(params, [grad slices])`` on the host -> on the device, the
+        gradients joined. One gradient's slices at a time, joined as they
+        land, so no more than one sliced copy is on the device."""
+        params = jax.device_put(host[0])
+        grads = [self._join(self._treedef, jax.device_put(g))
+                 for g in host[1]]
+        return jax.block_until_ready((params, grads))
 
     # -------------------------------------------------------------- cleanup
     def finish_round(self, ts, rnd: int) -> None:

@@ -97,6 +97,30 @@ def test_sgd_phase_matches_reference_on_both_backends(monkeypatch):
     assert wire["device_arrays"] == 0
 
 
+def test_sgd_phase_matches_reference_with_sliced_gradients(monkeypatch):
+    """The same with every gradient leaf over 1 KB crossing to the host
+    in slices and joined again for the combine: the reference's losses
+    and final params still come out."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.programs import jax_sgd
+    smoke = _load()
+    monkeypatch.setattr(jax_sgd, "FETCH_SLICE_BYTES", 1024)
+    cfg = get_config("smollm_360m", reduced=True)
+    sliced = jax.eval_shape(lambda t: jax_sgd.slice_leaves(t, 1024),
+                            jax_sgd.M.abstract_params(cfg))
+    assert max(len(s) for s in sliced) > 1
+    report = smoke.sgd_phase(
+        cfg, steps=3, n_micro=4, micro_batch=2, seq=32, n_handlers=3,
+        crash_prob=0.25, backends=["sharded"], seed=0, wall_limit=120.0)
+    assert report["ok"], report
+    run = report["runs"]["sharded"]
+    assert run["finished"] and run["param_rel_gap"] <= smoke.SGD_RTOL
+    np.testing.assert_allclose(run["losses"], report["reference"],
+                               rtol=smoke.SGD_RTOL)
+
+
 def test_program_puts_host_arrays_only():
     import jax
 

@@ -3,6 +3,8 @@ sweeps — all with ``interpret=True`` (the kernels compile for the TPU by
 default; the Pallas interpreter validates them here on the CPU). The
 TPU compiles themselves are in test_tpu_compile.py."""
 
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -117,6 +119,129 @@ def test_flash_matches_model_attention():
     # The (B, T, H, D) wrapper folds the same grouping itself.
     wrapped = attention(q, k, v, bq=32, bk=32, interpret=True)
     np.testing.assert_allclose(model_out, wrapped, rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------- flash path of the model step
+def _dense_gqa(q, k, v):
+    """Causal GQA attention in f32 with the whole score matrix."""
+    T, G = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest")
+    s = s / q.shape[-1] ** 0.5
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def _chunked_gqa(q, k, v):
+    from repro.models.attention import chunked_attention
+    G = q.shape[2] // k.shape[2]
+    return chunked_attention(q, jnp.repeat(k, G, axis=2),
+                             jnp.repeat(v, G, axis=2),
+                             q_chunk=128, kv_chunk=128)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128)],
+                         ids=["two_blocks", "one_block_two_kv_slices"])
+def test_flash_path_matches_chunked_and_dense(blocks):
+    """The model's flash path (the Pallas splash kernel, interpreted)
+    gives the output and dq/dk/dv of the jnp chunked path and of a dense
+    f32 reference: GQA with G = 3, hd 64, T = 256, causal, two batch
+    rows and two KV heads; in two blocks, or in one whose KV is computed
+    in two slices."""
+    from repro.models.attention import flash_attention
+    B, T, Hkv, G, D = 2, 256, 2, 3, 64
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, T, Hkv * G, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, T, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, T, Hkv, D), jnp.float32)
+    cot = jax.random.normal(ks[3], (B, T, Hkv * G, D), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, blocks=blocks, interpret=True)
+
+    def out_and_grads(f):
+        out, vjp = jax.vjp(f, q, k, v)
+        return (out, *vjp(cot))
+
+    got = out_and_grads(flash)
+    for ref in (out_and_grads(_dense_gqa), out_and_grads(_chunked_gqa)):
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4,
+                                       err_msg=name)
+    # The same through jax.grad of a scalar loss.
+    dq, dk, dv = jax.grad(lambda *a: jnp.sum(flash(*a) * cot),
+                          argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip((dq, dk, dv), got[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def _dispatch_cases():
+    from repro.configs import get_config
+    smollm = get_config("smollm_360m").period[0].attn
+    danube = get_config("h2o_danube_1_8b").period[0].attn     # window 4096
+    mla = get_config("deepseek_v2_lite_16b").prefix[0].attn
+    mla_dk = mla.qk_nope_dim + mla.qk_rope_dim
+    # (id, T, dk, dv, cfg, q_offset, backend, meshed, expected)
+    return [
+        ("smollm_train_tpu", 2048, 64, 64, smollm, 0, "tpu", False, "flash"),
+        ("smollm_train_cpu", 2048, 64, 64, smollm, 0, "cpu", False,
+         "chunked"),
+        ("smollm_under_mesh", 2048, 64, 64, smollm, 0, "tpu", True,
+         "chunked"),
+        ("mla_dk_ne_dv", 2048, mla_dk, mla.v_head_dim, mla, 0, "tpu",
+         False, "chunked"),
+        ("q_offset", 2048, 64, 64, smollm, 16, "tpu", False, "chunked"),
+        ("t_off_the_lanes", 1000, 64, 64, smollm, 0, "tpu", False,
+         "chunked"),
+        ("t_under_a_lane", 100, 64, 64, smollm, 0, "tpu", False, "chunked"),
+        ("t_one_block", 384, 64, 64, smollm, 0, "tpu", False, "flash"),
+        ("t_in_768_blocks", 1536, 64, 64, smollm, 0, "tpu", False, "flash"),
+        ("window_covers_t", 2048, 80, 80, danube, 0, "tpu", False, "flash"),
+        ("window_under_t", 8192, 80, 80, danube, 0, "tpu", False,
+         "chunked"),
+        ("softcap", 2048, 64, 64, replace(smollm, softcap=30.0), 0, "tpu",
+         False, "chunked"),
+    ]
+
+
+@pytest.mark.parametrize("case", _dispatch_cases(), ids=lambda c: c[0])
+def test_attention_dispatch(case):
+    from repro.models.attention import attention_impl
+    _, T, dk, dv, cfg, q_offset, backend, meshed, expected = case
+    assert attention_impl(T, dk, dv, cfg, q_offset, backend=backend,
+                          meshed=meshed) == expected
+
+
+def test_attention_dispatch_sees_an_active_mesh():
+    """Under ``use_rules`` the model is traced for GSPMD: the flash path
+    is refused there even on a TPU backend."""
+    from jax.sharding import Mesh
+    from repro.configs import get_config
+    from repro.distributed import sharding as shd
+    from repro.models.attention import attention_impl
+    cfg = get_config("smollm_360m").period[0].attn
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    assert not shd.rules_active()
+    with shd.use_rules(shd.DEFAULT_RULES, mesh):
+        assert shd.rules_active()
+        assert attention_impl(2048, 64, 64, cfg, 0, backend="tpu",
+                              meshed=shd.rules_active()) == "chunked"
+    assert not shd.rules_active()
+
+
+def test_cpu_train_grad_has_no_pallas_call():
+    """On the CPU the gradient of the loss traces the same jnp attention
+    as before the flash path existed: no ``pallas_call`` anywhere."""
+    from repro.configs import get_config
+    from repro.configs.base import Shape, input_specs
+    from repro.models import model as M
+    cfg = get_config("smollm_360m", reduced=True)
+    params = M.abstract_params(cfg)
+    batch = input_specs(cfg, Shape("micro", "train", 256, 2))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, b: M.train_loss(p, cfg, b)[0]))(params, batch)
+    assert "pallas_call" not in str(jaxpr)
 
 
 # ------------------------------------------------------------------- ssd

@@ -198,3 +198,59 @@ def test_moe_respects_history_limit():
     res = ACANCloud(_moe_cfg(history_limit=4), program=prog).run()
     steps = [s for s, _ in res.loss_history]
     assert steps == list(range(6, 10))    # trimmed to the newest 4
+
+
+@pytest.mark.parametrize("limit", [64, 1 << 20], ids=["sliced", "whole"])
+def test_gradient_slices_rejoin_exactly(limit):
+    """A gradient leaf crosses to the host in slices of at most ``limit``
+    bytes along its first axis (one row at least), and the combine's join
+    gives back the tree bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.programs import jax_sgd
+    tree = {"w": jnp.arange(35, dtype=jnp.float32).reshape(7, 5),
+            "loss": jnp.float32(3.0),
+            "stack": {"u": jnp.arange(24, dtype=jnp.bfloat16).reshape(3, 2, 4)},
+            "wide": jnp.ones((2, 100), jnp.float32)}
+    slices = jax.device_get(jax.jit(jax_sgd.slice_leaves,
+                                    static_argnums=1)(tree, limit))
+    for x, parts in zip(jax.tree.leaves(tree), slices):
+        row = x.nbytes // x.shape[0] if x.ndim else x.nbytes
+        assert all(p.nbytes <= max(limit, row) for p in parts)
+        assert (len(parts) > 1) == (x.nbytes > limit and x.ndim > 0
+                                    and x.shape[0] > 1)
+    back = jax.jit(jax_sgd.join_leaves, static_argnums=0)(
+        jax.tree.structure(tree), slices)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_device_calls_stay_on_the_programs_threads():
+    """Under kills of the Manager and every handler, each grad call and
+    each update runs on one of the program's own device threads, never on
+    the handler or Manager threads the fault plane replaces."""
+    import threading
+
+    from repro.configs import get_config
+    from repro.programs import jax_sgd
+    prog = jax_sgd.JAXSGDProgram(get_config("smollm_360m", reduced=True),
+                                 steps=6, n_micro=2, micro_batch=2, seq=16,
+                                 seed=3)
+    seen = []
+    for name in ("grad_fn", "sgd_update"):
+        fn = getattr(prog, name)
+
+        def spy(*a, _fn=fn):
+            seen.append(threading.current_thread().name)
+            return _fn(*a)
+        setattr(prog, name, spy)
+    res = ACANCloud(CloudConfig(
+        n_handlers=2, handler_batch=1, wall_limit=120.0, initial_timeout=0.002,
+        fault_plan=FaultPlan(interval=0.2, p_manager_crash=1.0,
+                             p_handler_crash=1.0, seed=5)),
+        program=prog).run()
+    assert res.finished and res.manager_revivals >= 1
+    assert seen and all(n.startswith("jax_sgd-device") for n in seen)
+    assert len(set(seen)) <= jax_sgd.DEVICE_THREADS

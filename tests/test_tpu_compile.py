@@ -88,3 +88,27 @@ def test_smollm_grad_step_fits_one_chip(one_chip):
              + ma.temp_size_in_bytes)
     assert 0.7e9 < ma.argument_size_in_bytes < 0.8e9   # 362 M bf16 params
     assert total < V5E_HBM_BYTES, total
+
+
+def test_smollm_flash_grad_step_fits_one_chip(one_chip, monkeypatch):
+    """The same grad step as the chip traces it: the attention dispatch
+    sees a TPU backend (here the CPU is the default backend), so every
+    layer's attention is the Pallas splash kernel, forward and backward;
+    it compiles for one v5e chip and fits its HBM."""
+    from repro.configs import get_config
+    from repro.configs.base import Shape, input_specs
+    from repro.models import attention as A
+    from repro.models import model as M
+    from repro.programs.jax_sgd import JAXSGDProgram
+    impl = A.attention_impl
+    monkeypatch.setattr(A, "attention_impl", lambda *a, backend, meshed:
+                        impl(*a, backend="tpu", meshed=meshed))
+    cfg = get_config("smollm_360m")
+    prog = JAXSGDProgram(cfg, steps=1, n_micro=4, micro_batch=4, seq=2048)
+    params = _on(one_chip, M.abstract_params(cfg))
+    batch = _on(one_chip, input_specs(cfg, Shape("micro", "train", 2048, 4)))
+    compiled = prog.grad_fn.lower(params, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < V5E_HBM_BYTES

@@ -35,6 +35,7 @@ SPANS = {    # name -> its ids
     "acan.manager.gss_timeout": {"rnd", "epoch", "pending", "issued"},
     "acan.fault.fire": {"manager", "handlers"},
     "acan.fault.revive": {"role", "index"},
+    "acan.model.attention": {"impl", "seq", "heads"},
 }
 N_MICRO = 2
 
@@ -120,6 +121,16 @@ def test_every_span_is_written_with_its_ids(faulty):
                 and set(e["ids"]) == ids | {"bytes"}
                 and e["ids"]["uploaded"] == 1), (name, e["ids"])
     assert {e["name"] for e in events} == set(SPANS)
+
+
+def test_attention_instant_names_the_path(faulty):
+    """Tracing the gradient program records the attention path it took
+    (the jnp chunked one on the CPU) with the sequence and head count."""
+    prog, _, events = faulty
+    got = _named(events, "acan.model.attention")
+    a = prog.cfg.period[0].attn
+    assert got and all(e["ids"] == {"impl": "chunked", "seq": 16,
+                                    "heads": a.n_heads} for e in got)
 
 
 def test_each_child_lies_inside_its_parent(faulty):
