@@ -185,6 +185,7 @@ class _StageRun:
     deadline: float = 0.0
     waiting: bool = False            # pouch issued, barrier open
     met_early: bool = False          # barrier met under strict_timeout
+    reissue: bool = False            # the pouch re-publishes a task
 
 
 @dataclass
@@ -586,8 +587,9 @@ class Manager:
         # Re-issues are tasks published a second time (timeout
         # stragglers) — NOT later pouches of a stage wider than
         # pouch_size, whose tasks are being published for the first time.
-        self.reissued += sum(
-            1 for t in pouch if content_key(t) in run.issued)
+        again = sum(1 for t in pouch if content_key(t) in run.issued)
+        self.reissued += again
+        run.reissue = again > 0
         run.issued.update(content_key(t) for t in pouch)
         # Barrier target: stage done-marks already present + this pouch.
         # In-flight stragglers from a previous round are always at the
@@ -623,7 +625,15 @@ class Manager:
                     epoch=self.epoch, pending=len(still),
                     issued=len(run.pouch))
         done_frac = 1.0 - len(still) / max(len(run.pouch), 1)
-        self.controller.update(not still, elapsed, done_frac)
+        # A met pouch that re-published a task teaches the controller
+        # nothing: its time runs from the re-issue, so a straggler that
+        # was still running lands moments later and reads as a fast
+        # stage. Learnt, that halved the timeout, the next stage timed
+        # out as well, and its duplicates slowed the stage after it: a
+        # cascade of re-issues (PERF.md). A timed-out pouch still grows
+        # the timeout.
+        if still or not run.reissue:
+            self.controller.update(not still, elapsed, done_frac)
         if self.cfg.adaptive_pouch:
             # Utilisation proxy: how full this pouch ran relative to the
             # controller's current size — a stage's last pouch is usually

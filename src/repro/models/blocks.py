@@ -13,6 +13,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.distributed.sharding import shard_act
 from repro.models.attention import (AttnCfg, decode_attention, gqa_attention,
                                     mla_decode_attention)
@@ -138,40 +139,40 @@ def cache_specs(lcfg: LayerCfg, batch: int, cache_len: int, dtype) -> dict:
 # Attention paths
 # ---------------------------------------------------------------------------
 
-def _qkv(h, p, a: AttnCfg, positions):
+def _qkv(h, p, a: AttnCfg, positions, eps: float):
     B, T, _ = h.shape
     q = (h @ p["wq"] + p.get("bq", 0)).reshape(B, T, a.n_heads, a.head_dim)
     k = (h @ p["wk"] + p.get("bk", 0)).reshape(B, T, a.n_kv_heads, a.head_dim)
     v = (h @ p["wv"] + p.get("bv", 0)).reshape(B, T, a.n_kv_heads, a.head_dim)
     if a.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
+        q = rms_norm(q, p["q_norm"], eps)
+        k = rms_norm(k, p["k_norm"], eps)
     q = apply_rope(q, positions, a.rope_theta)
     k = apply_rope(k, positions, a.rope_theta)
     return q, k, v
 
 
-def _mla_qkv(h, p, a: AttnCfg, positions):
+def _mla_qkv(h, p, a: AttnCfg, positions, eps: float):
     B, T, _ = h.shape
     qd = a.qk_nope_dim + a.qk_rope_dim
     q = (h @ p["wq"]).reshape(B, T, a.n_heads, qd)
     q_nope, q_rope = q[..., :a.qk_nope_dim], q[..., a.qk_nope_dim:]
     q_rope = apply_rope(q_rope, positions, a.rope_theta)
     dkv = h @ p["w_dkv"]
-    c = rms_norm(dkv[..., :a.kv_lora_rank], p["ln_ckv"])
+    c = rms_norm(dkv[..., :a.kv_lora_rank], p["ln_ckv"], eps)
     kr = apply_rope(dkv[..., None, a.kv_lora_rank:], positions, a.rope_theta)
     return q_nope, q_rope, c, kr[..., 0, :]
 
 
 def attn_core(p, h, lcfg: LayerCfg, pos0: int = 0, want_cache: bool = False,
-              q_chunk: int = 512, kv_chunk: int = 512):
+              q_chunk: int = 512, kv_chunk: int = 512, *, eps: float):
     """Attention on already-normed input ``h``; returns (out, cache)."""
     a = lcfg.attn
     B, T, _ = h.shape
     positions = pos0 + jnp.arange(T)[None, :]
     cache = None
     if a.is_mla:
-        q_nope, q_rope, c, kr = _mla_qkv(h, p, a, positions)
+        q_nope, q_rope, c, kr = _mla_qkv(h, p, a, positions, eps)
         k_nope = jnp.einsum("btr,rhn->bthn", c, p["w_uk"])
         v = jnp.einsum("btr,rhv->bthv", c, p["w_uv"])
         k = jnp.concatenate(
@@ -185,7 +186,7 @@ def attn_core(p, h, lcfg: LayerCfg, pos0: int = 0, want_cache: bool = False,
         if want_cache:
             cache = {"c": c, "kr": kr}
     else:
-        q, k, v = _qkv(h, p, a, positions)
+        q, k, v = _qkv(h, p, a, positions, eps)
         q = shard_act(q, ("attn_batch", "seq", "heads", None))
         out = gqa_attention(q, k, v, a, q_offset=pos0,
                             q_chunk=q_chunk, kv_chunk=kv_chunk)
@@ -193,14 +194,14 @@ def attn_core(p, h, lcfg: LayerCfg, pos0: int = 0, want_cache: bool = False,
         if want_cache:
             cache = {"k": k, "v": v}
     if lcfg.post_norm:
-        out = rms_norm(out, p["post_ln"])
+        out = rms_norm(out, p["post_ln"], eps)
     return out, cache
 
 
 def attn_train(p, x, lcfg: LayerCfg, pos0: int = 0, want_cache: bool = False,
-               q_chunk: int = 512, kv_chunk: int = 512):
-    out, cache = attn_core(p, rms_norm(x, p["ln"]), lcfg, pos0, want_cache,
-                           q_chunk, kv_chunk)
+               q_chunk: int = 512, kv_chunk: int = 512, *, eps: float):
+    out, cache = attn_core(p, rms_norm(x, p["ln"], eps), lcfg, pos0,
+                           want_cache, q_chunk, kv_chunk, eps=eps)
     return x + out, cache
 
 
@@ -222,14 +223,14 @@ def attn_cache_from_prefill(cache_full: dict, lcfg: LayerCfg) -> dict:
     return {k: _ring_store(v, a.window) for k, v in cache_full.items()}
 
 
-def _attn_decode_core(p, h, cache, cur_len, lcfg: LayerCfg):
+def _attn_decode_core(p, h, cache, cur_len, lcfg: LayerCfg, *, eps: float):
     """h: (B, d) already normed. Returns (out (B, d), cache')."""
     a = lcfg.attn
     B = h.shape[0]
     positions = jnp.full((B, 1), cur_len, jnp.int32)
     h = h[:, None]                               # (B,1,d)
     if a.is_mla:
-        q_nope, q_rope, c, kr = _mla_qkv(h, p, a, positions)
+        q_nope, q_rope, c, kr = _mla_qkv(h, p, a, positions, eps)
         S = cache["c"].shape[1]
         idx = jnp.mod(cur_len, S)
         cache = {
@@ -241,7 +242,7 @@ def _attn_decode_core(p, h, cache, cur_len, lcfg: LayerCfg):
                                    cache["kr"], p["w_uk"], p["w_uv"], valid, a)
         out = out.reshape(B, -1) @ p["wo"]
     else:
-        q, k, v = _qkv(h, p, a, positions)
+        q, k, v = _qkv(h, p, a, positions, eps)
         S = cache["k"].shape[1]
         idx = jnp.mod(cur_len, S)
         cache = {
@@ -252,14 +253,14 @@ def _attn_decode_core(p, h, cache, cur_len, lcfg: LayerCfg):
         out = decode_attention(q[:, 0], cache["k"], cache["v"], valid, a)
         out = out.reshape(B, -1) @ p["wo"]
     if lcfg.post_norm:
-        out = rms_norm(out, p["post_ln"])
+        out = rms_norm(out, p["post_ln"], eps)
     return out, cache
 
 
-def attn_decode(p, x, cache, cur_len, lcfg: LayerCfg):
+def attn_decode(p, x, cache, cur_len, lcfg: LayerCfg, *, eps: float):
     """x: (B, d); cur_len: scalar — tokens already in cache."""
-    out, cache = _attn_decode_core(p, rms_norm(x, p["ln"]), cache, cur_len,
-                                   lcfg)
+    out, cache = _attn_decode_core(p, rms_norm(x, p["ln"], eps), cache,
+                                   cur_len, lcfg, eps=eps)
     return x + out, cache
 
 
@@ -272,24 +273,33 @@ def _mamba_proj(h, p):
             h @ p["w_dt"])
 
 
-def mamba_train(p, x, lcfg: LayerCfg, want_cache: bool = False):
+def mamba_train(p, x, lcfg: LayerCfg, want_cache: bool = False, *,
+                eps: float):
+    """Mamba-2 mixer over a whole sequence. The SSD (the chunked scan
+    and its D skip) runs under the ``acan.ssd`` named scope, so its ops
+    carry that scope in the device trace, and each trace records an
+    ``acan.model.ssd`` instant (ids ``impl``, ``seq``, ``chunk``,
+    ``heads``, ``d_state``)."""
     m = lcfg.mamba
     B, T, _ = x.shape
-    h = rms_norm(x, p["ln"])
+    h = rms_norm(x, p["ln"], eps)
     z, xin, B_, C_, dt_raw = _mamba_proj(h, p)
     xin_pre, B_pre, C_pre = xin, B_, C_
-    xin = jax.nn.silu(_causal_conv(xin, p["conv_x"]))
-    B_ = jax.nn.silu(_causal_conv(B_, p["conv_B"]))
-    C_ = jax.nn.silu(_causal_conv(C_, p["conv_C"]))
+    xin = jax.nn.silu(_causal_conv(xin, p["conv_x"], p["conv_x_bias"]))
+    B_ = jax.nn.silu(_causal_conv(B_, p["conv_B"], p["conv_B_bias"]))
+    C_ = jax.nn.silu(_causal_conv(C_, p["conv_C"], p["conv_C_bias"]))
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
     x4 = xin.reshape(B, T, m.n_heads, m.head_dim)
     x4 = shard_act(x4, ("batch", "seq", "heads", None))
     B5 = B_.reshape(B, T, m.n_groups, m.d_state)
     C5 = C_.reshape(B, T, m.n_groups, m.d_state)
-    y, state = ssd_chunked(x4, dt, A, B5, C5, p["D"], m.chunk)
+    trace.instant("acan.model.ssd", impl="chunked", seq=T,
+                  chunk=min(m.chunk, T), heads=m.n_heads, d_state=m.d_state)
+    with jax.named_scope("acan.ssd"):
+        y, state = ssd_chunked(x4, dt, A, B5, C5, p["D"], m.chunk)
     y = y.reshape(B, T, m.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_gate"])
+    y = rms_norm(y * jax.nn.silu(z), p["norm_gate"], eps)
     out = y @ p["w_out"]
     cache = None
     if want_cache:
@@ -300,23 +310,24 @@ def mamba_train(p, x, lcfg: LayerCfg, want_cache: bool = False):
     return x + out, cache
 
 
-def _conv_step(buf, new, kernel):
+def _conv_step(buf, new, kernel, bias):
     """buf: (B, K-1, C) past pre-conv inputs; new: (B, C). Returns conv
     output (B, C) and updated buf."""
     window = jnp.concatenate([buf, new[:, None]], axis=1)     # (B, K, C)
-    out = jnp.einsum("bkc,kc->bc", window, kernel)
+    dt = window.dtype
+    out = jnp.einsum("bkc,kc->bc", window, kernel.astype(dt)) + bias.astype(dt)
     return out, window[:, 1:]
 
 
-def mamba_decode(p, x, cache, lcfg: LayerCfg):
+def mamba_decode(p, x, cache, lcfg: LayerCfg, *, eps: float):
     m = lcfg.mamba
     B, _ = x.shape
-    h = rms_norm(x, p["ln"])
+    h = rms_norm(x, p["ln"], eps)
     z, xin, B_, C_, dt_raw = (h @ p["w_z"], h @ p["w_x"], h @ p["w_B"],
                               h @ p["w_C"], h @ p["w_dt"])
-    cx_out, ncx = _conv_step(cache["cx"], xin, p["conv_x"])
-    cB_out, ncB = _conv_step(cache["cB"], B_, p["conv_B"])
-    cC_out, ncC = _conv_step(cache["cC"], C_, p["conv_C"])
+    cx_out, ncx = _conv_step(cache["cx"], xin, p["conv_x"], p["conv_x_bias"])
+    cB_out, ncB = _conv_step(cache["cB"], B_, p["conv_B"], p["conv_B_bias"])
+    cC_out, ncC = _conv_step(cache["cC"], C_, p["conv_C"], p["conv_C_bias"])
     xin = jax.nn.silu(cx_out)
     B_ = jax.nn.silu(cB_out)
     C_ = jax.nn.silu(cC_out)
@@ -327,7 +338,7 @@ def mamba_decode(p, x, cache, lcfg: LayerCfg):
         B_.reshape(B, m.n_groups, m.d_state),
         C_.reshape(B, m.n_groups, m.d_state), p["D"])
     y = y.reshape(B, m.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_gate"])
+    y = rms_norm(y * jax.nn.silu(z), p["norm_gate"], eps)
     out = y @ p["w_out"]
     return x + out, {"state": state, "cx": ncx, "cB": ncB, "cC": ncC}
 
@@ -336,7 +347,7 @@ def mamba_decode(p, x, cache, lcfg: LayerCfg):
 # FFN + full block
 # ---------------------------------------------------------------------------
 
-def ffn_core(p, h, lcfg: LayerCfg):
+def ffn_core(p, h, lcfg: LayerCfg, *, eps: float):
     """FFN on already-normed input; returns (out, aux)."""
     if lcfg.ffn_kind == "dense":
         out = dense_ffn(h, p, lcfg.dense)
@@ -347,48 +358,50 @@ def ffn_core(p, h, lcfg: LayerCfg):
         out, aux = moe_ffn(flat, p, lcfg.moe)
         out = out.reshape(B, T, d)
     if lcfg.post_norm:
-        out = rms_norm(out, p["post_ln"])
+        out = rms_norm(out, p["post_ln"], eps)
     return out, aux
 
 
-def ffn_apply(p, x, lcfg: LayerCfg):
+def ffn_apply(p, x, lcfg: LayerCfg, *, eps: float):
     """Pre-norm residual FFN. Returns (x', aux_loss)."""
     if lcfg.ffn_kind == "none":
         return x, jnp.float32(0.0)
-    out, aux = ffn_core(p, rms_norm(x, p["ln"]), lcfg)
+    out, aux = ffn_core(p, rms_norm(x, p["ln"], eps), lcfg, eps=eps)
     return x + out, aux
 
 
 def block_train(p, x, lcfg: LayerCfg, pos0: int = 0, want_cache: bool = False,
-                q_chunk: int = 512, kv_chunk: int = 512):
-    """Full block for train/prefill. Returns (x, aux, cache|None)."""
+                q_chunk: int = 512, kv_chunk: int = 512, *, eps: float):
+    """Full block for train/prefill. Returns (x, aux, cache|None).
+    ``eps`` is every norm's epsilon (``ModelConfig.norm_eps``)."""
     if lcfg.parallel and lcfg.mixer == "attn" and lcfg.ffn_kind != "none":
         # Command-R parallel residual: shared input norm, summed branches.
-        h = rms_norm(x, p["attn"]["ln"])
+        h = rms_norm(x, p["attn"]["ln"], eps)
         a_out, cache = attn_core(p["attn"], h, lcfg, pos0, want_cache,
-                                 q_chunk, kv_chunk)
-        f_out, aux = ffn_core(p["ffn"], h, lcfg)
+                                 q_chunk, kv_chunk, eps=eps)
+        f_out, aux = ffn_core(p["ffn"], h, lcfg, eps=eps)
         x = x + a_out + f_out
         return shard_act(x, ("batch", "seq", "embed")), aux, cache
     if lcfg.mixer == "attn":
         x, cache = attn_train(p["attn"], x, lcfg, pos0, want_cache,
-                              q_chunk, kv_chunk)
+                              q_chunk, kv_chunk, eps=eps)
     else:
-        x, cache = mamba_train(p["mamba"], x, lcfg, want_cache)
+        x, cache = mamba_train(p["mamba"], x, lcfg, want_cache, eps=eps)
     x = shard_act(x, ("batch", "seq", "embed"))
-    x, aux = ffn_apply(p.get("ffn"), x, lcfg)
+    x, aux = ffn_apply(p.get("ffn"), x, lcfg, eps=eps)
     return x, aux, cache
 
 
-def block_decode(p, x, cache, cur_len, lcfg: LayerCfg):
+def block_decode(p, x, cache, cur_len, lcfg: LayerCfg, *, eps: float):
     if lcfg.parallel and lcfg.mixer == "attn" and lcfg.ffn_kind != "none":
-        h = rms_norm(x, p["attn"]["ln"])
-        a_out, cache = _attn_decode_core(p["attn"], h, cache, cur_len, lcfg)
-        f_out, _ = ffn_core(p["ffn"], h[:, None], lcfg)
+        h = rms_norm(x, p["attn"]["ln"], eps)
+        a_out, cache = _attn_decode_core(p["attn"], h, cache, cur_len, lcfg,
+                                         eps=eps)
+        f_out, _ = ffn_core(p["ffn"], h[:, None], lcfg, eps=eps)
         return x + a_out + f_out[:, 0], cache
     if lcfg.mixer == "attn":
-        x, cache = attn_decode(p["attn"], x, cache, cur_len, lcfg)
+        x, cache = attn_decode(p["attn"], x, cache, cur_len, lcfg, eps=eps)
     else:
-        x, cache = mamba_decode(p["mamba"], x, cache, lcfg)
-    x2, _ = ffn_apply(p.get("ffn"), x[:, None], lcfg)
+        x, cache = mamba_decode(p["mamba"], x, cache, lcfg, eps=eps)
+    x2, _ = ffn_apply(p.get("ffn"), x[:, None], lcfg, eps=eps)
     return x2[:, 0], cache

@@ -44,10 +44,20 @@ def mamba_specs(d_model: int, cfg: MambaCfg, dtype) -> dict:
         "w_B": ParamSpec((d_model, gn), ("embed", None), dtype),
         "w_C": ParamSpec((d_model, gn), ("embed", None), dtype),
         "w_dt": ParamSpec((d_model, cfg.n_heads), ("embed", "heads"), dtype),
-        "conv_x": ParamSpec((cfg.d_conv, cfg.d_inner), (None, "mlp"), dtype,
+        # The depthwise conv's taps and its bias (published, split as the
+        # taps are) are per-channel vectors, held in float32 as the norms,
+        # A_log, D and dt_bias are and applied in the activations' dtype:
+        # a bfloat16 tap near 1 has an ulp of 2^-7, above most SGD steps.
+        "conv_x": ParamSpec((cfg.d_conv, cfg.d_inner), (None, "mlp"),
+                            jnp.float32, init="small"),
+        "conv_B": ParamSpec((cfg.d_conv, gn), (None, None), jnp.float32,
                             init="small"),
-        "conv_B": ParamSpec((cfg.d_conv, gn), (None, None), dtype, init="small"),
-        "conv_C": ParamSpec((cfg.d_conv, gn), (None, None), dtype, init="small"),
+        "conv_C": ParamSpec((cfg.d_conv, gn), (None, None), jnp.float32,
+                            init="small"),
+        "conv_x_bias": ParamSpec((cfg.d_inner,), ("mlp",), jnp.float32,
+                                 init="zeros"),
+        "conv_B_bias": ParamSpec((gn,), (None,), jnp.float32, init="zeros"),
+        "conv_C_bias": ParamSpec((gn,), (None,), jnp.float32, init="zeros"),
         "A_log": ParamSpec((cfg.n_heads,), ("heads",), jnp.float32, init="zeros"),
         "D": ParamSpec((cfg.n_heads,), ("heads",), jnp.float32, init="ones"),
         "dt_bias": ParamSpec((cfg.n_heads,), ("heads",), jnp.float32,
@@ -58,16 +68,17 @@ def mamba_specs(d_model: int, cfg: MambaCfg, dtype) -> dict:
     }
 
 
-def _causal_conv(x, kernel):
-    """x: (B, T, C); kernel: (K, C) depthwise causal conv."""
+def _causal_conv(x, kernel, bias):
+    """x: (B, T, C); kernel: (K, C), bias: (C,) depthwise causal conv, in
+    x's dtype."""
     K = kernel.shape[0]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     out = jax.lax.conv_general_dilated(
-        xp, kernel[:, None, :],
+        xp, kernel.astype(x.dtype)[:, None, :],
         window_strides=(1,), padding="VALID",
         dimension_numbers=("NWC", "WIO", "NWC"),
         feature_group_count=x.shape[-1])
-    return out
+    return out + bias.astype(x.dtype)
 
 
 def _segsum(dA):

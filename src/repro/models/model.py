@@ -52,6 +52,7 @@ class ModelConfig:
                               # all-reduce (§Perf it4) — per-device logits
                               # stay small (chunk/data × vocab/model)
     rules_name: str = "tp"            # tp | fsdp  (sharding profile)
+    norm_eps: float = 1e-6            # every RMSNorm's epsilon
     long_context_ok: bool = False     # eligible for long_500k
     notes: str = ""
 
@@ -151,7 +152,8 @@ def _backbone(params, cfg: ModelConfig, h, want_cache: bool = False):
     aux = aux0
     for lcfg, p in zip(cfg.prefix, params["prefix"]):
         h, a, c = block_train(p, h, lcfg, want_cache=want_cache,
-                              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+                              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                              eps=cfg.norm_eps)
         aux = aux + a
         prefix_caches.append(c)
 
@@ -160,14 +162,15 @@ def _backbone(params, cfg: ModelConfig, h, want_cache: bool = False):
         caches = []
         for j, lcfg in enumerate(cfg.period):
             h, a, c = block_train(p_stack[j], h, lcfg, want_cache=want_cache,
-                                  q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+                                  q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                                  eps=cfg.norm_eps)
             aux = aux + a
             caches.append(c)
         return (h, aux), (tuple(caches) if want_cache else 0)
 
     body = period_body if want_cache else _remat(period_body, cfg)
     (h, aux), period_caches = jax.lax.scan(body, (h, aux), params["period"])
-    h = rms_norm(h, params["final_ln"])
+    h = rms_norm(h, params["final_ln"], cfg.norm_eps)
     caches = None
     if want_cache:
         caches = {"prefix": tuple(prefix_caches), "period": period_caches}
@@ -238,19 +241,20 @@ def decode_step(params, cfg: ModelConfig, cache, batch):
 
     new_prefix = []
     for lcfg, p, c in zip(cfg.prefix, params["prefix"], cache["prefix"]):
-        h, c = block_decode(p, h, c, cur, lcfg)
+        h, c = block_decode(p, h, c, cur, lcfg, eps=cfg.norm_eps)
         new_prefix.append(c)
 
     def body(h, xs):
         p_stack, c_stack = xs
         new_c = []
         for j, lcfg in enumerate(cfg.period):
-            h, cj = block_decode(p_stack[j], h, c_stack[j], cur, lcfg)
+            h, cj = block_decode(p_stack[j], h, c_stack[j], cur, lcfg,
+                                 eps=cfg.norm_eps)
             new_c.append(cj)
         return h, tuple(new_c)
 
     h, new_period = jax.lax.scan(body, h, (params["period"], cache["period"]))
-    h = rms_norm(h, params["final_ln"])
+    h = rms_norm(h, params["final_ln"], cfg.norm_eps)
     logits = (h @ _head_matrix(params, cfg)).astype(jnp.float32)
     return logits, {"prefix": tuple(new_prefix), "period": new_period}
 

@@ -193,6 +193,57 @@ def test_reissued_counts_only_straggler_republications():
                                                  mgr.timed_out_tasks)
 
 
+@pytest.mark.parametrize("straggler_s", [0.1, 0.6, 5.0])
+def test_gss_timeout_learns_nothing_from_a_met_reissue(monkeypatch,
+                                                        straggler_s):
+    """A pouch that re-published a task and is then met leaves the
+    timeout where the deadline put it, whether the straggler lands
+    moments after its re-issue (which, learnt, halved the timeout and
+    set off a cascade of re-issues) or its copy runs the task from the
+    start (which, learnt from the first issue, ratcheted the timeout up
+    after every handler kill). First-time pouches adapt it as always."""
+    import types
+
+    from repro.core import manager as manager_mod
+    from repro.core.manager import _StageRun
+    from repro.core.tasks import content_key
+    clock = [100.0]
+    monkeypatch.setattr(manager_mod, "time", types.SimpleNamespace(
+        monotonic=lambda: clock[0], time=lambda: clock[0]))
+    ts = TupleSpace()
+    prog = MLPProgram([LayerSpec(4, 1)], epochs=1, n_samples=1, seed=0)
+    mgr = Manager(ts=ts, program=prog,
+                  cfg=ManagerConfig(initial_timeout=1.0))
+    tasks = [TaskDesc("jaxgrad", 0, 0, 0, 0, 0, m, m + 1) for m in range(2)]
+    run = _StageRun(rnd=0, name="grad", order=0, tasks=tasks,
+                    done_pat=mgr._stage_done_pattern(tasks))
+
+    def done(t):
+        ts.put(("done",) + content_key(t), "h")
+
+    mgr._start_pouch(run)                   # both tasks, first issue
+    clock[0] += 1.0
+    done(tasks[0])
+    mgr._finish_pouch(run, barrier_met=False)   # deadline, one pending
+    assert mgr.controller.timeout == pytest.approx(1.3)   # x (1 + 0.6/2)
+    mgr._start_pouch(run)                   # the straggler re-issued
+    assert mgr.reissued == 1 and run.pouch == [tasks[1]]
+    clock[0] += straggler_s
+    done(tasks[1])
+    mgr._finish_pouch(run, barrier_met=True)
+    assert mgr.controller.timeout == pytest.approx(1.3)
+
+    # A first-time pouch is timed from its own issue: EMA toward 1.3x.
+    later = [TaskDesc("jaxgrad", 0, 1, 1, 0, 0, 0, 1)]
+    run = _StageRun(rnd=1, name="grad", order=0, tasks=later,
+                    done_pat=mgr._stage_done_pattern(later))
+    mgr._start_pouch(run)
+    clock[0] += 0.5
+    done(later[0])
+    mgr._finish_pouch(run, barrier_met=True)
+    assert mgr.controller.timeout == pytest.approx(0.5 * 1.3 + 0.65 * 0.5)
+
+
 def test_moe_respects_history_limit():
     prog = MoERoutingProgram(steps=10, seed=0)
     res = ACANCloud(_moe_cfg(history_limit=4), program=prog).run()

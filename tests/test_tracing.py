@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
@@ -131,6 +132,27 @@ def test_attention_instant_names_the_path(faulty):
     a = prog.cfg.period[0].attn
     assert got and all(e["ids"] == {"impl": "chunked", "seq": 16,
                                     "heads": a.n_heads} for e in got)
+
+
+def test_ssd_instant_names_the_path(tmp_path):
+    """Compiling a Mamba-2 gradient program while a profiler runs records
+    the SSD path it took with the sequence, chunk, heads and state size."""
+    cfg = get_config("mamba2_2_7b", reduced=True)
+    m = cfg.period[0].mamba
+    fn = jax.jit(jax.value_and_grad(
+        lambda p, b: M.train_loss(p, cfg, b)[0]))
+    tok = jax.ShapeDtypeStruct((2, 40), jnp.int32)
+    log_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(log_dir)
+    try:
+        fn.lower(M.abstract_params(cfg),
+                 {"tokens": tok, "labels": tok}).compile()
+    finally:
+        jax.profiler.stop_trace()
+    got = _named(_events(log_dir), "acan.model.ssd")
+    assert got and all(e["ids"] == {
+        "impl": "chunked", "seq": 40, "chunk": m.chunk, "heads": m.n_heads,
+        "d_state": m.d_state} for e in got)
 
 
 def test_each_child_lies_inside_its_parent(faulty):
