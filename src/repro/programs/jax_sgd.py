@@ -30,6 +30,13 @@ process running the op and the combine moves arrays to the device: each
 handler uploads a param version once (``_device_params``), and the
 combine averages and applies the update with one jitted call.
 
+The op and the combine run in one process (a program with ops of its
+own cannot run on a process fleet), so the combine takes its operands
+from the device copies the ops left there: the gradients the ops kept
+(``_keep_grads``) and the param version they read. The space still gets
+every gradient and every committed version as host data, and a copy
+missing on the device (after a Manager revival, say) goes up from it.
+
 Each move between host and device is a span of its own (``acan.jax_sgd.*``,
 :mod:`repro.core.trace`) that carries the ``bytes`` it moved.
 
@@ -162,8 +169,9 @@ class JAXSGDProgram(WorkloadProgram):
 
         #: ``(params, batch) -> (loss, grads)`` — what every op runs.
         self.grad_fn = jax.jit(jax.value_and_grad(loss_fn))
-        # A gradient crosses to the host, and back for the combine, as
-        # the slices of slice_leaves (see FETCH_SLICE_BYTES).
+        # A gradient crosses to the host, and back to a combine that has
+        # no device copy of it, as the slices of slice_leaves (see
+        # FETCH_SLICE_BYTES).
         self._slice = jax.jit(
             functools.partial(slice_leaves, limit=FETCH_SLICE_BYTES))
         self._join = jax.jit(join_leaves, static_argnums=0)
@@ -180,8 +188,13 @@ class JAXSGDProgram(WorkloadProgram):
         #: ``(params, [grads per micro]) -> params``: mean-of-grads SGD,
         #: accumulated in float32 — what every combine runs.
         self.sgd_update = jax.jit(sgd_update)
-        # (version, device copy) of the last param version an op read.
+        # (version, device copy) of the last param version an op read or
+        # a combine committed.
         self._dev_params: tuple[int, object] | None = None
+        # {(step, micro): (version, device gradient)} that the ops kept
+        # for the combine, all of param version _grads_version.
+        self._dev_grads: dict[tuple[int, int], tuple[int, object]] = {}
+        self._grads_version = 0
         self._dev_lock = threading.Lock()
         self.registry = OpRegistry(parent=ensure_builtin_ops())
         self.registry.register(OpSpec(
@@ -191,9 +204,12 @@ class JAXSGDProgram(WorkloadProgram):
 
     # ---------------------------------------------------------------- setup
     def setup(self, ts) -> None:
+        fresh = ts.try_read(("params", ANY)) is None
         with self._dev_lock:
             self._dev_params = None
-        if ts.try_read(("params", ANY)) is None:
+            if fresh:            # a new job: its versions count from 0
+                self._dev_grads, self._grads_version = {}, 0
+        if fresh:
             params = M.init_params(self.cfg, jax.random.PRNGKey(self.seed))
             ts.put(("params", 0), jax.device_get(params))
 
@@ -217,6 +233,26 @@ class JAXSGDProgram(WorkloadProgram):
             else:
                 sp.set_metadata(uploaded=0)
             return self._dev_params[1]
+
+    def _keep_grads(self, step: int, micro: int, version: int,
+                    grads) -> None:
+        """Keep the device gradient of ``(step, micro)``, computed on param
+        ``version``, for the combine. Only one on its own step's version
+        can be combined, and only those of the newest version are kept
+        (at most ``n_micro`` trees): a newer one drops them, an older one
+        (a straggler whose step has committed) is not kept. A duplicate
+        run is bitwise the same, so the first stays."""
+        with self._dev_lock:
+            if version != step or version < self._grads_version:
+                return
+            self._drop_grads(version)
+            self._dev_grads.setdefault((step, micro), (version, grads))
+
+    def _drop_grads(self, version: int) -> None:
+        """Drop the kept gradients of versions before ``version``, and
+        keep none of them from now on. The caller holds ``_dev_lock``."""
+        if version > self._grads_version:
+            self._dev_grads, self._grads_version = {}, version
 
     # ---------------------------------------------------------- stage graph
     def n_rounds(self) -> int:
@@ -269,6 +305,7 @@ class JAXSGDProgram(WorkloadProgram):
                     loss, grads = self._on_device(lambda: jax.device_get(
                         (out[0], self._slice(out[1]))))
                     sp.set_metadata(bytes=nbytes((loss, grads)))
+                self._keep_grads(t.step, micro, hit[0][1], out[1])
             items.append((("gpart", t.step, micro), (float(loss), grads)))
         return items
 
@@ -287,9 +324,12 @@ class JAXSGDProgram(WorkloadProgram):
             parts = [p[1] for p in parts]
             mean_loss = float(np.mean([p[0] for p in parts]))
             with span("acan.jax_sgd.combine.upload", step=rnd) as sp:
-                host = (hit[1], [p[1] for p in parts])
-                params, grads = self._on_device(self._upload, host)
-                sp.set_metadata(bytes=nbytes(host))
+                params, grads = self._resident(rnd)
+                reused = sum(x is not None for x in [params, *grads])
+                (params, grads), moved = self._on_device(
+                    self._upload, params, grads, hit[1],
+                    [p[1] for p in parts])
+                sp.set_metadata(reused=reused, bytes=moved)
             with span("acan.jax_sgd.combine.update", step=rnd):
                 new = self._on_device(lambda: jax.block_until_ready(
                     self.sgd_update(params, grads)))
@@ -297,21 +337,47 @@ class JAXSGDProgram(WorkloadProgram):
             with span("acan.jax_sgd.combine.fetch", step=rnd) as sp:
                 new_params = self._on_device(jax.device_get, new)
                 sp.set_metadata(bytes=nbytes(new_params))
-            del new
             with span("acan.jax_sgd.combine.commit", step=rnd):
                 record_loss(ts, rnd, mean_loss, mgr.cfg.history_limit)
                 if mgr.window.commit(0, rnd):    # §5.4 exactly-once
+                    # The next round's ops read the new version here.
+                    with self._dev_lock:
+                        self._dev_params = (rnd + 1, new)
+                        self._drop_grads(rnd + 1)
                     ts.put(("params", rnd + 1), new_params)
                     ts.delete(("params", rnd))
 
-    def _upload(self, host):
-        """``(params, [grad slices])`` on the host -> on the device, the
-        gradients joined. One gradient's slices at a time, joined as they
-        land, so no more than one sliced copy is on the device."""
-        params = jax.device_put(host[0])
-        grads = [self._join(self._treedef, jax.device_put(g))
-                 for g in host[1]]
-        return jax.block_until_ready((params, grads))
+    def _resident(self, rnd: int):
+        """The combine's operands of step ``rnd`` that are on the device
+        already, None for each that is not: the params if the ops last
+        read version ``rnd``, and each micro's gradient if an op kept it
+        computed on that version (a straggler of step ``rnd`` may have
+        read the next)."""
+        with self._dev_lock:
+            params = self._dev_params
+            grads = [self._dev_grads.get((rnd, m))
+                     for m in range(self.n_micro)]
+        return (params[1] if params is not None and params[0] == rnd
+                else None,
+                [g[1] if g is not None and g[0] == rnd else None
+                 for g in grads])
+
+    def _upload(self, params, grads, host_params, host_grads):
+        """``(params, grads)`` on the device, each None uploaded from its
+        host copy, and the bytes that took. A gradient goes up as its
+        slices, joined as they land, one gradient at a time, so no more
+        than one sliced copy is on the device."""
+        moved = 0
+        if params is None:
+            params = jax.device_put(host_params)
+            moved += nbytes(host_params)
+        grads = list(grads)
+        for m, g in enumerate(grads):
+            if g is None:
+                grads[m] = self._join(self._treedef,
+                                      jax.device_put(host_grads[m]))
+                moved += nbytes(host_grads[m])
+        return jax.block_until_ready((params, grads)), moved
 
     # -------------------------------------------------------------- cleanup
     def finish_round(self, ts, rnd: int) -> None:

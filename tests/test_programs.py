@@ -305,3 +305,254 @@ def test_device_calls_stay_on_the_programs_threads():
     assert res.finished and res.manager_revivals >= 1
     assert seen and all(n.startswith("jax_sgd-device") for n in seen)
     assert len(set(seen)) <= jax_sgd.DEVICE_THREADS
+
+
+# ------------------------------------------- JAX-SGD's resident operands
+N_MICRO = 2
+
+
+def _sgd(steps: int = 3):
+    from repro.configs import get_config
+    from repro.programs.jax_sgd import JAXSGDProgram
+    return JAXSGDProgram(get_config("smollm_360m", reduced=True),
+                         steps=steps, n_micro=N_MICRO, micro_batch=2,
+                         seq=16, seed=3)
+
+
+class _Span(dict):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def set_metadata(self, **ids):
+        self.update(ids)
+
+
+def _spy_spans(monkeypatch) -> list:
+    """Stand in for the program's spans: each one's name and ids, those
+    set on it as it ran included, whether a profiler runs or not."""
+    from repro.programs import jax_sgd
+    got = []
+
+    def span(name, **ids):
+        got.append((name, s := _Span(ids)))
+        return s
+    monkeypatch.setattr(jax_sgd, "span", span)
+    return got
+
+
+def _ids(spans, name) -> list:
+    return [s for n, s in spans if n == name]
+
+
+def _run_grads(prog, ts, rnd: int) -> None:
+    """Run round ``rnd``'s grad tasks by hand and publish their parts."""
+    from repro.core.executor import ExecContext
+    ctx = ExecContext(ts)
+    for t in prog.stage_tasks(ts, rnd, "grad"):
+        for key, val in prog._grad_parts(ctx, [t]):
+            ts.put(key, val)
+
+
+def _mgr():
+    import types
+
+    from repro.core.conflict import CommitWindow
+    return types.SimpleNamespace(window=CommitWindow(), cfg=ManagerConfig())
+
+
+def _assert_trees_equal(a, b) -> None:
+    import jax
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and np.array_equal(x, y)
+
+
+def _sgd_cloud(prog, **kw):
+    cfg = dict(n_handlers=2, handler_batch=1, wall_limit=120.0)
+    cfg.update(kw)
+    cloud = ACANCloud(CloudConfig(**cfg), program=prog)
+    res = cloud.run()
+    assert res.finished
+    return res, cloud.ts
+
+
+def test_resident_operands_update_bitwise_as_the_fallback(monkeypatch):
+    """N steps whose combines take the ops' device copies end on the same
+    params, bit for bit, and the same losses as N steps whose combines
+    find no device copy (the store read as empty) and upload every
+    operand from the space; the store never holds more than n_micro
+    gradient trees."""
+    spans = _spy_spans(monkeypatch)
+    steps = 3
+    resident = _sgd(steps)
+    real_keep, most = resident._keep_grads, [0]
+
+    def keep(*a):
+        real_keep(*a)
+        most[0] = max(most[0], len(resident._dev_grads))
+    resident._keep_grads = keep
+    res_a, ts_a = _sgd_cloud(resident)
+    reused_a = [s["reused"] for s in _ids(spans, "acan.jax_sgd.combine.upload")]
+
+    spans.clear()
+    fallback = _sgd(steps)
+    fallback._resident = lambda rnd: (None, [None] * N_MICRO)
+    res_b, ts_b = _sgd_cloud(fallback)
+    ups = _ids(spans, "acan.jax_sgd.combine.upload")
+
+    assert 0 < most[0] <= N_MICRO
+    assert sum(reused_a) > 0
+    assert ups and all(s["reused"] == 0 for s in ups)
+    from repro.programs.jax_sgd import nbytes
+    tree = nbytes(ts_b.try_read(("params", steps))[1])
+    assert all(s["bytes"] == (N_MICRO + 1) * tree for s in ups)
+    assert res_a.loss_history == res_b.loss_history
+    assert len(res_a.loss_history) == steps
+    _assert_trees_equal(ts_a.try_read(("params", steps))[1],
+                        ts_b.try_read(("params", steps))[1])
+
+
+def test_gradient_of_another_version_is_never_combined(monkeypatch):
+    """A kept gradient of step ``rnd`` computed on another param version
+    (a straggler that read the next one) is refused when kept and, were
+    it in the store, skipped by the combine: that micro goes up from the
+    space, ``reused`` counts the rest, and the update is the fallback's."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.programs.jax_sgd import nbytes
+    spans = _spy_spans(monkeypatch)
+    prog = _sgd()
+    ts = TupleSpace()
+    prog.setup(ts)
+    _run_grads(prog, ts, 0)
+    # The same inputs for a fallback combine on a space of its own.
+    twin = TupleSpace()
+    for key in [("params", 0)] + [("gpart", 0, m) for m in range(N_MICRO)]:
+        twin.put(key, ts.try_read(key)[1])
+
+    version, grads = prog._dev_grads[(0, 1)]
+    assert version == 0
+    prog._keep_grads(0, 1, 1, grads)            # a straggler on version 1
+    assert prog._dev_grads[(0, 1)][1] is grads
+    # Plant a wrong-version gradient, zeros, for micro 1.
+    prog._dev_grads[(0, 1)] = (1, jax.tree.map(jnp.zeros_like, grads))
+    prog.combine(ts, 0, "grad", _mgr())
+    (up,) = _ids(spans, "acan.jax_sgd.combine.upload")
+    tree = nbytes(ts.try_read(("params", 1))[1])
+    assert up["reused"] == N_MICRO             # params and micro 0
+    assert up["bytes"] == tree                 # micro 1 from the space
+
+    prog._dev_params = None                    # nothing resident
+    prog.combine(twin, 0, "grad", _mgr())
+    (_, up) = _ids(spans, "acan.jax_sgd.combine.upload")
+    assert up["reused"] == 0 and up["bytes"] == (N_MICRO + 1) * tree
+    _assert_trees_equal(ts.try_read(("params", 1))[1],
+                        twin.try_read(("params", 1))[1])
+
+
+def test_commit_drops_older_gradients_and_keeps_the_new_params(monkeypatch):
+    """The store holds at most n_micro trees, the first of duplicate runs;
+    a committing combine drops every gradient of an older version and
+    leaves the new version on the device, so the next round's ops upload
+    no params and its combine uploads nothing."""
+    spans = _spy_spans(monkeypatch)
+    prog = _sgd()
+    ts = TupleSpace()
+    prog.setup(ts)
+    _run_grads(prog, ts, 0)
+    assert sorted(prog._dev_grads) == [(0, m) for m in range(N_MICRO)]
+    first = prog._dev_grads[(0, 0)][1]
+    from repro.core.executor import ExecContext
+    (t0,) = [t for t in prog.stage_tasks(ts, 0, "grad") if t.out_lo == 0]
+    prog._grad_parts(ExecContext(ts), [t0])    # a straggler's duplicate
+    assert len(prog._dev_grads) == N_MICRO
+    assert prog._dev_grads[(0, 0)][1] is first
+
+    mgr = _mgr()
+    prog.combine(ts, 0, "grad", mgr)
+    assert mgr.window.committed_step == {0: 0}
+    assert prog._dev_grads == {} and prog._grads_version == 1
+    assert prog._dev_params[0] == 1
+    _assert_trees_equal(prog._dev_params[1], ts.try_read(("params", 1))[1])
+    prog._keep_grads(0, 0, 0, first)           # lands after its commit
+    assert prog._dev_grads == {}
+
+    spans.clear()
+    _run_grads(prog, ts, 1)
+    ups = _ids(spans, "acan.jax_sgd.grad.params_upload")
+    assert len(ups) == N_MICRO
+    assert all(s["version"] == 1 and s["uploaded"] == 0 for s in ups)
+    assert sorted(prog._dev_grads) == [(1, m) for m in range(N_MICRO)]
+    assert all(v == 1 for v, _ in prog._dev_grads.values())
+    prog.combine(ts, 1, "grad", mgr)
+    (up,) = _ids(spans, "acan.jax_sgd.combine.upload")
+    assert up["reused"] == N_MICRO + 1 and up["bytes"] == 0
+    assert prog._dev_grads == {} and prog._dev_params[0] == 2
+
+
+def _jax_sgd_crash_sites() -> list:
+    """The crash points of the JAX-SGD job: each TS mutation site of the
+    program (crashed after the op lands), and the combine's loss record,
+    crashed before it lands — after the new params' fetch, before the
+    commit — in the second step."""
+    import sys
+    from pathlib import Path
+    repo = str(Path(__file__).resolve().parent.parent)
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from tools.crash_lint import site_registry
+    sites = {s.site_id: s for s in site_registry()}
+    out = [(sites["manager:program.record_loss:put[losshist]#0"],
+            "before", 2)]
+    out += [(s, "after", 1) for s in sites.values()
+            if s.path.endswith("programs/jax_sgd.py")]
+    return out
+
+
+@pytest.fixture(scope="module")
+def crash_free():
+    res, ts = _sgd_cloud(_sgd(), ts_backend="crashpoint+sharded",
+                         fault_plan=FaultPlan(interval=1e9))
+    return res.loss_history, ts.try_read(("params", 3))[1]
+
+
+@pytest.mark.parametrize("site,when,nth", _jax_sgd_crash_sites(),
+                         ids=lambda v: getattr(v, "site_id", str(v)))
+def test_manager_crash_in_the_combine_commits_once(monkeypatch, crash_free,
+                                                   site, when, nth):
+    """A Manager crash at each of the JAX-SGD job's crash points, the one
+    between the combine's fetch and its commit included, is revived and
+    commits every version once: the same losses and, bit for bit, the
+    same final params as the crash-free run, and only that version left
+    in the space. Crashed before its commit, the combine's re-run takes
+    the kept gradients and uploads only the params, which the revived
+    Manager no longer holds on the device."""
+    from repro.core.space import ANY, CrashSpec, find_crashpoint
+    spans = _spy_spans(monkeypatch)
+    prog = _sgd()
+    cloud = ACANCloud(CloudConfig(
+        n_handlers=2, handler_batch=1, wall_limit=120.0,
+        ts_backend="crashpoint+sharded", fault_plan=FaultPlan(interval=1e9)),
+        program=prog)
+    cp = find_crashpoint(cloud.ts.backend)
+    cp.arm(CrashSpec(site_id=site.site_id, role=site.role, path=site.path,
+                     line=site.line, end_line=site.end_line, nth=nth,
+                     when=when))
+    res = cloud.run()
+    assert res.finished
+    assert cp.firings, "the armed site was never reached"
+    assert res.manager_revivals >= 1
+    losses, params = crash_free
+    assert res.loss_history == losses
+    assert cloud.ts.keys(("params", ANY)) == [("params", 3)]
+    _assert_trees_equal(cloud.ts.try_read(("params", 3))[1], params)
+    if when == "before":                       # step 1's combine, twice
+        ups = [s for s in _ids(spans, "acan.jax_sgd.combine.upload")
+               if s["step"] == 1]
+        assert [s["reused"] for s in ups] == [N_MICRO + 1, N_MICRO]

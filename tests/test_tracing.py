@@ -28,7 +28,7 @@ SPANS = {    # name -> its ids
     "acan.jax_sgd.grad.fetch": {"step", "micro", "bytes"},
     "acan.jax_sgd.combine": {"step"},
     "acan.jax_sgd.combine.gather": {"step"},
-    "acan.jax_sgd.combine.upload": {"step", "bytes"},
+    "acan.jax_sgd.combine.upload": {"step", "reused", "bytes"},
     "acan.jax_sgd.combine.update": {"step"},
     "acan.jax_sgd.combine.fetch": {"step", "bytes"},
     "acan.jax_sgd.combine.commit": {"step"},
@@ -200,18 +200,24 @@ def test_recover_and_fault_spans_follow_the_kills(faulty):
 
 def test_span_bytes_under_kills_are_whole_trees(faulty):
     """Each transfer span carries the bytes of the trees it moved, a
-    revived handler's param upload and a re-issued gradient too."""
+    revived handler's param upload and a re-issued gradient too; the
+    combine's upload, those of the operands it found no device copy of
+    (a revived Manager holds no params on the device)."""
     prog, res, events = faulty
     t = _tree_bytes(prog)
     want = {"acan.jax_sgd.grad.params_upload": t,
             "acan.jax_sgd.grad.compute": nbytes(prog.pipe.batch_at(0)),
             "acan.jax_sgd.grad.fetch": t + 4,            # float32 loss
-            "acan.jax_sgd.combine.upload": (1 + N_MICRO) * t,
             "acan.jax_sgd.combine.fetch": t}
     for name, size in want.items():
         got = [e["ids"]["bytes"] for e in _named(events, name)
                if "bytes" in e["ids"]]
         assert got and set(got) == {size}, name
+    ups = _named(events, "acan.jax_sgd.combine.upload")
+    assert ups and all(0 <= e["ids"]["reused"] <= 1 + N_MICRO
+                       and e["ids"]["bytes"]
+                       == (1 + N_MICRO - e["ids"]["reused"]) * t
+                       for e in ups)
     uploads = _named(events, "acan.jax_sgd.grad.params_upload")
     assert sum(e["ids"]["uploaded"] for e in uploads) >= len(
         {e["ids"]["version"] for e in uploads if e["ids"]["uploaded"]})
@@ -232,12 +238,18 @@ def test_span_bytes_count_the_trees_exactly(clean):
     assert calls >= steps * N_MICRO
     uploads = sum(e["ids"]["uploaded"] for e in
                   _named(events, "acan.jax_sgd.grad.params_upload"))
-    assert uploads >= steps
-    assert len(_named(events, "acan.jax_sgd.combine.upload")) == steps
-    # Up: each new param version once, each batch, and each combine's
-    # params and N_MICRO grads. Down: each gradient with its float32
-    # loss, each combine's new params.
-    up = uploads * t + calls * batch + steps * (1 + N_MICRO) * t
+    assert uploads >= 1
+    combines = _named(events, "acan.jax_sgd.combine.upload")
+    assert len(combines) == steps
+    # The ops' device copies are the combine's operands: it uploads only
+    # those it found none of, and the next round's ops read the version
+    # it committed on the device.
+    assert any(e["ids"]["reused"] == 1 + N_MICRO for e in combines)
+    missed = sum(1 + N_MICRO - e["ids"]["reused"] for e in combines)
+    # Up: the param versions the ops found no device copy of, each
+    # batch, and each combine's missing operands. Down: each gradient
+    # with its float32 loss, each combine's new params.
+    up = uploads * t + calls * batch + missed * t
     down = calls * (t + 4) + steps * t
     assert sum(e["ids"].get("bytes", 0) for e in events) == up + down
     assert res.timed_out_tasks == sum(
