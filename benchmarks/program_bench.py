@@ -40,7 +40,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import time
 
 import numpy as np
 
@@ -214,24 +213,26 @@ def run_multi(smoke: bool, backend: str | None) -> dict:
 
 def run_jax(smoke: bool, backend: str | None) -> dict:
     from repro.configs import get_config
-    from repro.ts_exec.step_runner import ACANStepRunner, ACANTrainConfig
+    from repro.core.space import ANY
+    from repro.programs.jax_sgd import JAXSGDProgram
     steps = 4 if smoke else 8
-    runner = ACANStepRunner(
-        get_config("smollm_360m", reduced=True),
-        ACANTrainConfig(n_handlers=3, n_micro=3, micro_batch=2, seq=32,
-                        steps=steps, lr=0.05, timeout=20.0,
-                        handler_crash_prob=0.25, seed=0,
-                        ts_backend=_checked(backend)))
-    t0 = time.perf_counter()
-    res = runner.run()
-    wall = time.perf_counter() - t0
-    return {"name": "program_jax_sgd", "wall": wall, "ts_ops": 0,
-            "pouches": res.param_versions, "first": res.losses[0],
-            "last": res.losses[-1], "completed": len(res.losses) == steps,
-            "crashes": res.crashes, "reissues": res.reissues,
+    prog = JAXSGDProgram(get_config("smollm_360m", reduced=True),
+                         steps=steps, n_micro=3, micro_batch=2, seq=32,
+                         lr=0.05, handler_crash_prob=0.25, seed=0)
+    cloud = ACANCloud(CloudConfig(
+        n_handlers=3, handler_batch=1, ts_backend=_checked(backend),
+        initial_timeout=20.0, fault_plan=FaultPlan(interval=1e9)),
+        program=prog)
+    res = cloud.run()
+    losses = [l for _, l in sorted(res.loss_history)]
+    (_, versions), _ = cloud.ts.try_read(("params", ANY))
+    return {"name": "program_jax_sgd", "wall": res.wallclock,
+            "ts_ops": _ts_ops(res), "pouches": versions,
+            "first": losses[0], "last": losses[-1],
+            "completed": len(losses) == steps,
+            "crashes": prog.crashes, "reissues": res.reissues,
             "ts_clean": _ts_clean(res),
-            "ok": bool(len(res.losses) == steps
-                       and res.losses[-1] < res.losses[0])
+            "ok": bool(len(losses) == steps and losses[-1] < losses[0])
             and _ts_clean(res)}
 
 

@@ -100,9 +100,9 @@ def main() -> None:
                      f"pouches={row['pouches']} ts_ops={row['ts_ops']} "
                      f"mse={row['final_mse']}"))
 
-    # Control-plane scheduling rows (PR 2/4): poll vs event on the §6.1
-    # workload (including the ops-per-pouch gate ratio) plus the adaptive
-    # pouch-size row against the fixed §6 baseline.
+    # Control-plane scheduling rows: the event plane's ops per pouch on
+    # the §6.1 workload, the adaptive pouch-size row against the fixed §6
+    # baseline, and the pipeline/autotune/raced/process-fleet gates.
     from benchmarks import sched_bench as SB
     rows.extend(SB.bench_rows(smoke=not paper_scale))
 

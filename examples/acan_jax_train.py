@@ -13,30 +13,39 @@ sharded high-throughput tuple-space backend.
 
 from _example_args import protocol_audit, ts_backend_arg
 from repro.configs import get_config
-from repro.ts_exec.step_runner import ACANStepRunner, ACANTrainConfig
+from repro.core import ACANCloud, CloudConfig, FaultPlan
+from repro.core.space import ANY
+from repro.programs.jax_sgd import JAXSGDProgram
+
+N_HANDLERS, N_MICRO = 4, 4
 
 
 def main() -> None:
-    ts_backend = ts_backend_arg()
     cfg = get_config("deepseek_v2_lite_16b", reduced=True)
-    tcfg = ACANTrainConfig(n_handlers=4, n_micro=4, micro_batch=2, seq=32,
-                           steps=8, lr=0.05, timeout=30.0,
-                           handler_crash_prob=0.25, seed=0,
-                           ts_backend=ts_backend)
-    runner = ACANStepRunner(cfg, tcfg)
+    prog = JAXSGDProgram(cfg, steps=8, n_micro=N_MICRO, micro_batch=2,
+                         seq=32, lr=0.05, handler_crash_prob=0.25, seed=0)
+    # handler_batch=1: gradient tasks are heavy, so micro-batches spread
+    # across handlers instead of draining into one batch. The crashes
+    # are the program's own; the fault plane's interval faults are off.
+    cloud = ACANCloud(CloudConfig(
+        n_handlers=N_HANDLERS, handler_batch=1, ts_backend=ts_backend_arg(),
+        initial_timeout=30.0, fault_plan=FaultPlan(interval=1e9)),
+        program=prog)
     print(f"arch: {cfg.name} (reduced, MoE {cfg.period[0].moe.n_experts}e "
-          f"top-{cfg.period[0].moe.top_k}); {tcfg.n_handlers} handlers, "
-          f"{tcfg.n_micro} grad tasks/step, 25% crash prob/task, "
-          f"ts backend {type(runner.ts.backend).__name__}\n")
-    res = runner.run()
-    for i, l in enumerate(res.losses):
+          f"top-{cfg.period[0].moe.top_k}); {N_HANDLERS} handlers, "
+          f"{N_MICRO} grad tasks/step, 25% crash prob/task, "
+          f"ts backend {type(cloud.ts.backend).__name__}\n")
+    res = cloud.run()
+    losses = [l for _, l in sorted(res.loss_history)]
+    for i, l in enumerate(losses):
         print(f"step {i}: loss {l:.4f}")
-    print(f"\ncrashes: {res.crashes}  re-issues: {res.reissues}  "
-          f"param versions committed: {res.param_versions}")
-    assert res.losses[-1] < res.losses[0]
+    (_, versions), _ = cloud.ts.try_read(("params", ANY))
+    print(f"\ncrashes: {prog.crashes}  re-issues: {res.reissues}  "
+          f"param versions committed: {versions}")
+    assert losses[-1] < losses[0]
     print("loss decreased through crashes — ACAN semantics hold for real "
           "JAX training.")
-    protocol_audit(runner.ts.backend, res)
+    protocol_audit(cloud.ts.backend, res)
 
 
 if __name__ == "__main__":

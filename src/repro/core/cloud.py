@@ -42,8 +42,7 @@ from dataclasses import dataclass, field
 
 from repro.core.faults import FaultPlan, MonitorDaemon
 from repro.core.handler import Handler, HandlerCrash, HandlerTenant, SpeedBox
-from repro.core.manager import (Manager, ManagerConfig, ManagerCrash,
-                                validate_scheduling)
+from repro.core.manager import Manager, ManagerConfig, ManagerCrash
 from repro.core.program import WorkloadProgram
 from repro.core.space import (ANY, CONTROL_SCHEMAS, DEFAULT_NAMESPACE,
                               CrashPointFired, TSTimeout, TupleSpace,
@@ -87,7 +86,6 @@ class CloudConfig:
     data_noise: float = 0.0
     wall_limit: float = 600.0                      # hard safety limit (s)
     ts_backend: str | None = None                  # None -> $REPRO_TS_BACKEND
-    scheduling: str = "event"                      # "event" | "poll" baseline
     handler_batch: int = 16                        # tasks per take_batch
     history_limit: int = 10_000                    # thist/losshist cap
     adaptive_pouch: bool = False                   # PouchController in Manager
@@ -131,7 +129,6 @@ class CloudConfig:
     compute_mode: str = "sleep"
 
     def __post_init__(self) -> None:
-        validate_scheduling(self.scheduling)
         if self.fleet not in ("thread", "process"):
             raise ValueError(f"unknown fleet {self.fleet!r} "
                              f"(expected 'thread' | 'process')")
@@ -307,7 +304,6 @@ class ACANCloud:
             cfg=ManagerConfig(
                 task_cap=self.cfg.task_cap, pouch_size=self.cfg.pouch_size,
                 initial_timeout=self.cfg.initial_timeout,
-                scheduling=self.cfg.scheduling,
                 history_limit=self.cfg.history_limit,
                 adaptive_pouch=self.cfg.adaptive_pouch,
                 max_inflight_stages=self.cfg.max_inflight_stages,
@@ -377,7 +373,6 @@ class ACANCloud:
                     capacity=self.cfg.task_cap, lr=self.cfg.lr,
                     time_scale=self.cfg.time_scale,
                     batch_size=self.cfg.handler_batch,
-                    scheduling=self.cfg.scheduling,
                     registry=registry,
                     tenants=tenants,
                     autotune=self.cfg.autotune,
@@ -409,7 +404,7 @@ class ACANCloud:
             speed=self._speed_boxes[i].get(),      # re-draws land here
             capacity=cfg.task_cap, lr=cfg.lr,
             time_scale=cfg.time_scale, batch_size=cfg.handler_batch,
-            scheduling=cfg.scheduling, compute_mode=cfg.compute_mode,
+            compute_mode=cfg.compute_mode,
             autotune=cfg.autotune,
             namespaces=self.namespaces if self.multi else None,
             tenant_caps=(cfg.tenant_caps or None) if self.multi else None)
@@ -589,16 +584,8 @@ class ACANCloud:
         # keep the jobs alive through crashes): blocking reads per
         # namespace against the shared wall-limit deadline — each
         # completion put wakes us directly — sliced so that a failed
-        # role stops the run within FAILURE_QUANTUM. ("poll" scheduling
-        # keeps the busy-wait as the benchmark baseline.)
-        deadline = t0 + cfg.wall_limit
-        if cfg.scheduling == "poll":
-            while not all(self._finished(i) for i in range(n_programs)):
-                if time.monotonic() > deadline or self._failures:
-                    break
-                time.sleep(0.02)
-        else:
-            self._wait_finished(deadline)
+        # role stops the run within FAILURE_QUANTUM.
+        self._wait_finished(t0 + cfg.wall_limit)
         self.stop_event.set()
         dthread.join(timeout=2.0)
         # Quiesce the fleet before the shutdown protocol scan: a handler

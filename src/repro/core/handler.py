@@ -28,9 +28,6 @@ checked *inside* the sleep, so a crash genuinely interrupts in-flight work
 (the taken task tuples are lost with the handler — exactly the failure the
 timeout/retransmission discipline must cover).
 
-``scheduling="poll"`` preserves the pre-PR-2 single-get/50 ms-timeout
-loop as the measured baseline for ``benchmarks/sched_bench.py``.
-
 Multi-tenancy (PR 4): one handler fleet serves several co-resident
 programs on one physical space. Pass ``tenants`` — a mapping of
 namespace → :class:`HandlerTenant` (that program's
@@ -66,7 +63,6 @@ import numpy as np
 
 from repro.core.costmodel import OnlineCostModel, read_backlog
 from repro.core.executor import PreconditionUnmet, TaskExecutor
-from repro.core.manager import validate_scheduling
 from repro.core.program import OpRegistry, UnknownOp, ensure_builtin_ops
 from repro.core.tasks import TaskDesc, content_key
 from repro.core.space import (ANY, DEFAULT_NAMESPACE, TSTimeout, TupleSpace,
@@ -87,9 +83,7 @@ class HandlerTenant:
     cap are stored back (tagged, like a capability miss) for the rest of
     the fleet. Heterogeneous fleets use asymmetric caps to pin a
     big-task tenant to its big handlers while every handler still serves
-    (a trickle of) every namespace. ``None`` = uncapped; poll-mode
-    handlers take one task at a time, so the cap only shapes the batched
-    event loop."""
+    (a trickle of) every namespace. ``None`` = uncapped."""
     space: Any                          # TupleSpace | ScopedSpace
     registry: OpRegistry | None = None
     max_tasks: int | None = None
@@ -175,7 +169,6 @@ class Handler:
     batch_size: int = 16              # max tasks drained per take_batch
     take_timeout: float = 0.2         # crash/stop responsiveness bound
     store_backoff: float = 0.02       # own-tagged re-put skip window
-    scheduling: str = "event"         # "event" (batched) | "poll" (seed loop)
     #: How emulated compute burns its budget (PR 10): "sleep" (default —
     #: time.sleep releases the GIL, cheap and exact) or "spin" (a
     #: GIL-holding busy loop in ~1 ms crash-responsive slices). Spin is
@@ -294,13 +287,10 @@ class Handler:
         task's round is finished by now, take our own re-put back. The
         delete is ownership-guarded by VALUE, not object identity (which
         never matches over a :class:`RemoteBackend` — every read-back is
-        a fresh unpickled copy): event-loop re-puts carry a
+        a fresh unpickled copy): store re-puts carry a
         ``(wire, name, nonce)`` token unique to this incarnation, so a
         fresh Manager re-issue (a bare wire string) or another handler's
-        re-put (different name/nonce) always survives. Poll-loop stores
-        are untagged bare wire by design (the measured baseline); there
-        an equal read-back of a *finished* round is deleted — which is
-        exactly what the Manager's own sweep would do with it."""
+        re-put (different name/nonce) always survives."""
         if rt is None or task is None:
             return
         if task.step >= self._fence_base(rt):
@@ -338,7 +328,6 @@ class Handler:
             self._run()
 
     def _run(self) -> None:
-        validate_scheduling(self.scheduling)
         if self.compute_mode not in ("sleep", "spin"):
             raise ValueError(f"unknown compute_mode {self.compute_mode!r} "
                              f"(expected 'sleep' | 'spin')")
@@ -376,9 +365,7 @@ class Handler:
                             f"{tenant.max_tasks!r} for namespace {ns!r}")
                     self._caps[ns] = int(tenant.max_tasks)
             self._take_pat = task_take_pattern(set(self._rt))
-        if self.scheduling == "poll":
-            return self._run_poll()
-        return self._run_event()
+        self._run_event()
 
     # --------------------------------------------------------- event loop
     def _run_event(self) -> None:
@@ -622,43 +609,3 @@ class Handler:
             return (-backlog[ns], -secs)
 
         return sorted(groups, key=key)
-
-    # ---------------------------------------------------------- poll loop
-    def _run_poll(self) -> None:
-        """The pre-PR-2 loop: one 50 ms-timeout get per task, untagged
-        stores — the measured baseline for ``benchmarks/sched_bench.py``."""
-        while not self.stop_event.is_set():
-            self._maybe_crash()
-            try:
-                key, value = self.ts.get(self._take_pat, timeout=0.05)
-            except TSTimeout:
-                continue
-            wire, _ = _unpack_task(value)
-            task = TaskDesc.from_wire(wire)
-            rt = self._rt.get(key_namespace(key))
-            if rt is not None and task.step < self._fence_base(rt):
-                self.tasks_fenced += 1    # finished round: drop, don't run
-                continue
-            cost = (self._task_cost(task, rt.registry)
-                    if rt is not None else None)
-            if cost is None or cost > self.capacity:
-                self.ts.put(key, wire)
-                # Same late-re-put leak as the event loop's stores: the
-                # put can land after the Manager's final sweep (PR 6) —
-                # compensate here too (found by the PR 9 crash lint:
-                # this was the one uncompensated store re-put).
-                self._unstore_if_stale(key, wire, task, rt)
-                self.tasks_stored += 1
-                time.sleep(0.001)
-                continue
-            self._throttled_sleep(cost * self.time_scale
-                                  / max(self.speed.get(), 1e-6))
-            try:
-                written = rt.executor.execute(task)
-            except PreconditionUnmet:
-                self.tasks_discarded += 1
-                continue
-            rt.space.put(("done",) + content_key(task), self.name)
-            self.tasks_done += 1
-            if task.step < self._fence_base(rt):
-                self._undo_stale(rt, [task], written)

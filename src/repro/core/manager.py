@@ -50,11 +50,6 @@ dies mid-frontier, and the daemon revives a fresh Manager that re-runs
 every not-yet-combined stage from the done marks already in TS (covered
 by ``tests/test_acan_training.py`` and ``tests/test_pipeline.py``).
 
-``scheduling="poll"`` preserves the fixed-cadence control plane — kept
-as the measured baseline for ``benchmarks/sched_bench.py``, not for
-production use; it drives the same frontier, re-scanning each in-flight
-pouch every ``poll_quantum``.
-
 Multi-tenancy (PR 4): the Manager is tenant-agnostic — hand it a
 :class:`~repro.core.space.ScopedSpace` and every key it touches (tasks,
 done marks, the ``mstate`` cursor/frontier/rounds/epoch/finished
@@ -93,19 +88,6 @@ class ManagerCrash(Exception):
     """Injected fault — the Manager thread dies here."""
 
 
-#: Valid control-plane modes; the single validator shared by CloudConfig,
-#: ManagerConfig and Handler (each branches on the value — a typo must not
-#: silently select event mode).
-SCHEDULING_MODES = ("event", "poll")
-
-
-def validate_scheduling(value: str) -> str:
-    if value not in SCHEDULING_MODES:
-        raise ValueError(
-            f"scheduling must be one of {SCHEDULING_MODES}, got {value!r}")
-    return value
-
-
 @dataclass
 class ManagerConfig:
     """Control-plane knobs only — *what* runs is the program's business."""
@@ -113,9 +95,7 @@ class ManagerConfig:
     task_cap: float = 256.0          # 4^4, paper §6
     pouch_size: int = 100            # paper §6
     initial_timeout: float = 0.25
-    poll_quantum: float = 0.004      # poll-mode only: done-scan cadence
     strict_timeout: bool = False     # True = always wait the full timeout
-    scheduling: str = "event"        # "event" (blocking barrier) | "poll"
     #: Upper bound on one blocking slice of a pouch barrier. Barriers are
     #: event-driven (completion arrivals end them immediately); this only
     #: bounds (a) how stale a pending crash/stop event can go unnoticed
@@ -157,7 +137,6 @@ class ManagerConfig:
     effect_fence: bool = True
 
     def __post_init__(self) -> None:
-        validate_scheduling(self.scheduling)
         if self.max_inflight_stages < 1:
             raise ValueError("max_inflight_stages must be >= 1, got "
                              f"{self.max_inflight_stages}")
@@ -558,20 +537,10 @@ class Manager:
         return [t for t in tasks
                 if ("done",) + content_key(t) not in done]
 
-    def _pending_polled(self, tasks: list[TaskDesc]) -> list[TaskDesc]:
-        """Seed-style pending scan: one concrete try_read per task."""
-        return [t for t in tasks
-                if self.ts.try_read(("done",) + content_key(t)) is None]
-
-    def _scan_pending(self, tasks: list[TaskDesc],
-                      pat: tuple | None = None) -> list[TaskDesc]:
-        return (self._pending(tasks, pat) if self.cfg.scheduling == "event"
-                else self._pending_polled(tasks))
-
     # ------------------------------------------------- pouch round lifecycle
     def _start_pouch(self, run: _StageRun) -> None:
         """Evaluate the stage; complete it, or issue its next pouch."""
-        pending = self._scan_pending(run.tasks, run.done_pat)
+        pending = self._pending(run.tasks, run.done_pat)
         if not pending:
             self._complete_stage(run)
             return
@@ -612,11 +581,9 @@ class Manager:
         elapsed = time.monotonic() - run.t0
         # Barrier reached == stage count hit the target == every pouch
         # task has its mark (the count cannot overshoot, see above) — no
-        # need to re-scan. Poll mode re-scans, as the baseline always did.
-        if barrier_met and self.cfg.scheduling == "event":
-            still: list[TaskDesc] = []
-        else:
-            still = self._scan_pending(run.pouch, run.done_pat)
+        # need to re-scan.
+        still = ([] if barrier_met
+                 else self._pending(run.pouch, run.done_pat))
         if still:
             # The GSS deadline fired with tasks of the pouch pending: the
             # cause of every straggler re-issue.
@@ -811,28 +778,10 @@ class Manager:
         else:
             self._finish_pouch(run, barrier_met=True)
 
-    def _poll_tick(self) -> None:
-        """The fixed-cadence baseline: sleep one ``poll_quantum``, then
-        re-scan each in-flight pouch (one concrete try_read per task, as
-        the seed loop did) and evaluate the first stage that completed or
-        timed out."""
-        time.sleep(self.cfg.poll_quantum)
-        self._maybe_crash()
-        now = time.monotonic()
-        for run in self._priority():
-            if not run.waiting:
-                continue
-            still = self._pending_polled(run.pouch)
-            if (not still and not self.cfg.strict_timeout) \
-                    or now >= run.deadline:
-                self._finish_pouch(run, barrier_met=False)
-                return
-
     # ------------------------------------------------------------------ run
     def run(self) -> None:
-        # The role tag is thread-local; Manager.run() may execute on a
-        # borrowed thread (step_runner drives it on the caller's), so the
-        # context manager form restores whatever role that thread had.
+        # The role tag is thread-local; the context manager form restores
+        # whatever role the calling thread had.
         with role("manager"), self._recovering:
             self._recovery = self._recovering.enter_context(
                 span("acan.manager.recover"))
@@ -907,10 +856,7 @@ class Manager:
                 # frontier still omits the in-flight stages, so a revived
                 # Manager redoes them from the done marks.
                 return
-            if self.cfg.scheduling == "poll":
-                self._poll_tick()
-            else:
-                self._event_tick()
+            self._event_tick()
         if self.stop_event.is_set():
             return
         # Last reclaim before declaring completion: a handler "store"

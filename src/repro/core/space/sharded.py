@@ -13,9 +13,8 @@ Why it is fast:
   scanning unrelated live tuples.
 - **Concrete-pattern fast path.** A pattern with no ``ANY``/predicate
   fields can only match the identical key, so ``try_read``/``try_get``/
-  ``read``/``get`` become O(1) dict hits — this is the Manager's
-  done-mark polling hot path (``_pending`` issues one fully-concrete
-  ``try_read`` per task per poll).
+  ``read``/``get`` become O(1) dict hits (a done mark, a param version,
+  a task by its tid).
 
 Semantics match :class:`~repro.core.space.local.LocalBackend` exactly
 (one conformance suite runs over both): ``get`` is FIFO in global put

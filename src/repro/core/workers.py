@@ -102,7 +102,7 @@ class ProcessCrashEvent:
 def spawn_worker(addr: tuple | str, name: str, *, speed: float = 1.0,
                  capacity: float = 256.0, lr: float = 0.01,
                  time_scale: float = 2e-6, batch_size: int = 16,
-                 scheduling: str = "event", compute_mode: str = "sleep",
+                 compute_mode: str = "sleep",
                  autotune: bool = False, defer_ratio: float = 3.0,
                  namespaces: list[str] | None = None,
                  tenant_caps: dict | None = None) -> HandlerProcess:
@@ -123,7 +123,7 @@ def spawn_worker(addr: tuple | str, name: str, *, speed: float = 1.0,
             "--capacity", str(capacity), "--lr", str(lr),
             "--time-scale", str(time_scale),
             "--batch-size", str(batch_size),
-            "--scheduling", scheduling, "--compute-mode", compute_mode,
+            "--compute-mode", compute_mode,
             "--defer-ratio", str(defer_ratio)]
     if autotune:
         argv.append("--autotune")
@@ -157,7 +157,6 @@ def main(argv=None) -> int:
     ap.add_argument("--lr", type=float, default=0.01)
     ap.add_argument("--time-scale", type=float, default=2e-6)
     ap.add_argument("--batch-size", type=int, default=16)
-    ap.add_argument("--scheduling", default="event")
     ap.add_argument("--compute-mode", default="sleep")
     ap.add_argument("--autotune", action="store_true")
     ap.add_argument("--defer-ratio", type=float, default=3.0)
@@ -187,7 +186,7 @@ def main(argv=None) -> int:
     h = Handler(ts=ts, name=args.name, speed=SpeedBox(args.speed),
                 capacity=args.capacity, lr=args.lr,
                 time_scale=args.time_scale, batch_size=args.batch_size,
-                scheduling=args.scheduling, registry=None,
+                registry=None,
                 tenants=tenants, autotune=args.autotune,
                 defer_ratio=args.defer_ratio,
                 compute_mode=args.compute_mode, stop_event=stop)

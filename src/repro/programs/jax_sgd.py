@@ -14,11 +14,6 @@ Data-parallel SGD where every microbatch gradient is one ACAN task:
   window (handlers read params by version — a handler that crashed
   mid-task never corrupts anything; its task simply re-appears).
 
-This replaces the pre-PR-3 ``ts_exec/step_runner.py`` control loop,
-which re-implemented its own barrier/timeout/commit discipline: the
-Manager's pouch barrier, GSS deadline, straggler re-issue, and cursor
-checkpointing now come from the shared plane.
-
 The op closes over the jitted grad function and the data pipeline, so it
 registers in a **program-private** registry chained to the global one —
 two concurrent programs never collide.

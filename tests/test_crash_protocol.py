@@ -1,8 +1,8 @@
 """PR 9 crash-recovery protocol units: the ``_unstore_if_stale``
 compensation on store re-put paths, the Manager's persisted ``swept``
 cursor and post-checkpoint re-sweep, and deterministic end-to-end pins
-for the crash windows PR 9 closed (the poll-loop store re-put and the
-delete-free commit path).
+for the crash windows PR 9 closed (the capability-miss store re-put and
+the delete-free commit path).
 """
 
 import sys
@@ -213,24 +213,29 @@ def test_checkpoint_persists_swept():
 
 
 # ------------------------------------------------ deterministic e2e pins
-def test_poll_store_reput_crash_leaves_task_recoverable():
-    """The PR 9 bugfix site: the poll loop's capability-miss store
-    re-put. Crash *after* the put (before the compensation ran): the
-    task tuple is back in TS, so a revived handler simply re-takes it —
-    nothing is lost and nothing leaks."""
+#: The event loop's capability-miss store re-put (an unserved namespace,
+#: an unknown op or a too-big task goes back to the space, tagged).
+CAPABILITY_MISS_SITE = "handler:handler.Handler._run_event:put[?]#2"
+
+
+def test_event_store_reput_crash_leaves_task_recoverable():
+    """The handler's capability-miss store re-put. Crash *after* the put
+    (before the compensation ran): the task tuple is back in TS, so a
+    revived handler simply re-takes it — nothing is lost and nothing
+    leaks."""
     from tools.crash_lint import site_registry
     (site,) = [s for s in site_registry()
-               if s.site_id == "handler:handler.Handler._run_poll:put[?]#0"]
+               if s.site_id == CAPABILITY_MISS_SITE]
     ts = TupleSpace(backend="crashpoint+sharded")
     cp = find_crashpoint(ts.backend)
     cp.arm(CrashSpec(site_id=site.site_id, role="handler", path=site.path,
                      line=site.line, end_line=site.end_line))
-    # An op no registry knows: a capability miss, so the poll loop takes
+    # An op no registry knows: a capability miss, so the event loop takes
     # the task and stores it straight back — traversing the armed site.
     ts.put(("task", "t1"), TaskDesc(op="exotic", layer=0, data_id=0,
                                     step=0).to_wire())
     stop = threading.Event()
-    h = _handler(ts, scheduling="poll", stop_event=stop)
+    h = _handler(ts, stop_event=stop)
     died = []
 
     def body():
@@ -243,12 +248,15 @@ def test_poll_store_reput_crash_leaves_task_recoverable():
     th.start()
     th.join(timeout=10.0)
     stop.set()
-    assert died == [True], "armed poll store site never fired"
+    assert died == [True], "armed store site never fired"
     assert len(cp.firings) == 1
     assert cp.firings[0]["site"] == site.site_id
     # when="after": the re-put landed before the crash — the task tuple
-    # survives for the next handler incarnation.
-    assert ts.try_read(("task", "t1")) is not None
+    # survives, tagged by the storing handler, for the next incarnation.
+    hit = ts.try_read(("task", "t1"))
+    assert hit is not None
+    wire, stored_by, _nonce = hit[1]
+    assert TaskDesc.from_wire(wire).op == "exotic" and stored_by == "h0"
 
 
 def test_commit_and_finish_round_sites_recover_via_sweep():

@@ -277,21 +277,6 @@ def test_cotenants_complete_under_exp3_fault_plan():
     assert multi.ledger_ok
 
 
-def test_poll_equals_event_losses_per_program():
-    """Scheduling mode must not perturb either tenant's numerics."""
-    results = {}
-    for scheduling in ("event", "poll"):
-        cfg = _base(scheduling=scheduling)
-        multi = ACANCloud(cfg, programs=_programs(cfg, moe_steps=6)).run()
-        results[scheduling] = {
-            ns: [l for _, l in r.loss_history]
-            for ns, r in multi.per_program.items()}
-    for ns in ("mlp", "moe_routing"):
-        ev, po = results["event"][ns], results["poll"][ns]
-        assert len(ev) == len(po) and len(ev) > 0
-        np.testing.assert_allclose(ev, po, rtol=1e-3, atol=1e-5)
-
-
 def test_independent_cursor_recovery_per_tenant(ts):
     """Crashing ONE tenant's Manager mid-run leaves the other tenant's
     cursor/epoch untouched; the revived Manager resumes from its own

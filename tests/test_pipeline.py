@@ -1,6 +1,6 @@
 """The pipelined stage-DAG control plane (PR 5): frontier scheduling
 over `stage_deps`, chain-DAG ≡ sequential equivalence, crash-mid-
-frontier recovery from the persisted frontier, poll-mode parity, MoE
+frontier recovery from the persisted frontier, MoE
 per-expert overlap under an exp3-style fault plan, and the satellite
 fixes (PouchController revival clamp, HandlerTenant capacity caps)."""
 
@@ -88,22 +88,6 @@ def test_pipelined_mlp_trajectory_bit_identical_to_sequential(backend):
     assert len(ls) == len(lp) == 6
     np.testing.assert_array_equal(np.array(ls), np.array(lp))
     assert res_seq.ledger_ok and res_pipe.ledger_ok
-
-
-def test_poll_mode_parity_under_pipelining():
-    """The poll baseline drives the same frontier: poll ≡ event at the
-    same max_inflight_stages (numerics unperturbed by scheduling)."""
-    base = dict(layers=[LayerSpec(16, 16), LayerSpec(16, 1)], n_handlers=3,
-                epochs=1, n_samples=5, task_cap=32.0, pouch_size=64,
-                lr=0.05, time_scale=1e-6, initial_timeout=0.1,
-                fault_plan=FaultPlan(interval=1e9), seed=0, wall_limit=60.0,
-                max_inflight_stages=4)
-    res_event = ACANCloud(CloudConfig(**base, scheduling="event")).run()
-    res_poll = ACANCloud(CloudConfig(**base, scheduling="poll")).run()
-    le = [l for _, l in res_event.loss_history]
-    lp = [l for _, l in res_poll.loss_history]
-    assert len(le) == len(lp) == 5
-    np.testing.assert_allclose(le, lp, rtol=1e-4, atol=1e-6)
 
 
 # --------------------------------------------- crash-mid-frontier recovery

@@ -278,6 +278,32 @@ def test_gradient_slices_rejoin_exactly(limit):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_acan_step_runner_trains_and_survives_crashes():
+    """JAX-SGD through ACANCloud, with the program's own handler crashes
+    (a quarter of the grad tasks are dropped mid-task): it learns, and
+    commits every param version exactly once."""
+    from repro.configs import get_config
+    from repro.core.space import ANY
+    from repro.programs.jax_sgd import JAXSGDProgram
+    prog = JAXSGDProgram(get_config("smollm_360m", reduced=True), steps=6,
+                         n_micro=3, micro_batch=2, seq=32, lr=0.05,
+                         handler_crash_prob=0.25, seed=0)
+    # A dropped task waits out the GSS deadline before its re-issue; 5 s
+    # covers the first pouch's compile, and the controller adapts after.
+    cloud = ACANCloud(CloudConfig(
+        n_handlers=3, handler_batch=1, initial_timeout=5.0,
+        fault_plan=FaultPlan(interval=1e9), wall_limit=120.0), program=prog)
+    res = cloud.run()
+    losses = [l for _, l in sorted(res.loss_history)]
+    assert res.finished
+    assert len(losses) == 6
+    assert cloud.ts.keys(("params", ANY)) == [("params", 6)]  # exactly-once
+    assert losses[-1] < losses[0]           # it actually learns
+    assert all(np.isfinite(l) for l in losses)
+    # with 25% crash probability over >= 18 tasks we expect some re-issues
+    assert prog.crashes + res.reissues >= 1
+
+
 def test_device_calls_stay_on_the_programs_threads():
     """Under kills of the Manager and every handler, each grad call and
     each update runs on one of the program's own device threads, never on

@@ -1,9 +1,10 @@
 """End-to-end behaviour of the paper's system: the full ACAN pipeline
 (tuple space → manager → handlers → SGD) reproduces plain-numpy training
 exactly when faults are off, and the pieces compose into the training
-framework (model zoo + ACAN step runner + recovery)."""
+framework (model zoo + ACAN JAX-SGD program + recovery)."""
 
 import numpy as np
+import pytest
 
 from repro.core import (ACANCloud, CloudConfig, FaultPlan, LayerSpec,
                         TupleSpace, make_teacher_data)
@@ -45,16 +46,25 @@ def _numpy_reference_training(layers, X, Y, lr, epochs):
     return losses
 
 
-def test_acan_training_matches_numpy_reference():
+@pytest.mark.parametrize("plane", [
+    {},
+    # One task per take_batch: how every JAX-SGD job drains its tasks.
+    {"handler_batch": 1},
+    # A frontier of four stages: the next sample's forward overlaps the
+    # update of this one.
+    {"max_inflight_stages": 4},
+], ids=["default", "handler_batch_1", "frontier_4"])
+def test_acan_training_matches_numpy_reference(plane):
     """With no faults the distributed tuple-space pipeline must produce
     the same loss trajectory as sequential numpy SGD — the strongest
-    correctness statement for the paper's §5 task decomposition."""
+    correctness statement for the paper's §5 task decomposition — however
+    the control plane drains and overlaps the tasks."""
     layers = [LayerSpec(16, 16), LayerSpec(16, 1)]
     cfg = CloudConfig(layers=layers, n_handlers=3, epochs=1, n_samples=8,
                       task_cap=32.0, pouch_size=64, lr=0.05,
                       time_scale=5e-7, initial_timeout=0.1,
                       fault_plan=FaultPlan(interval=1e9), seed=0,
-                      wall_limit=60.0)
+                      wall_limit=60.0, **plane)
     res = ACANCloud(cfg).run()
     X, Y = make_teacher_data(layers, 8, 0)
     ref = _numpy_reference_training(layers, X, Y, 0.05, 1)

@@ -23,13 +23,10 @@ this sweep:
      ``checked`` leg,
    - the crashed role was actually **revived** (daemon counters).
 
-Sites inside ``Handler._run_poll`` are exercised with
-``scheduling="poll"`` (they are unreachable from the event loop), the
-rest under the default event scheduling. Sites whose code path the
-small job never takes (capability misses, autotune deferrals, MoE/JAX
-program sites) are reported ``unreached`` — the armed run must still
-match the baseline exactly, which is itself a gate (an armed-but-silent
-backend must be transparent).
+Sites whose code path the small job never takes (capability misses,
+autotune deferrals, MoE/JAX program sites) are reported ``unreached`` —
+the armed run must still match the baseline exactly, which is itself a
+gate (an armed-but-silent backend must be transparent).
 
 Usage::
 
@@ -87,10 +84,6 @@ def sweep_sites() -> list[Site]:
             if s.path in SWEEP_FILES and s.role in SWEEP_ROLES]
 
 
-def _scheduling_for(site: Site) -> str:
-    return "poll" if "_run_poll" in site.qualname else "event"
-
-
 def _sample_per_class(sites: list[Site], n: int) -> list[Site]:
     """Up to ``n`` sites per (protection class, role) pair — the CI
     smoke subset."""
@@ -108,7 +101,6 @@ def _sample_per_class(sites: list[Site], n: int) -> list[Site]:
 class SiteResult:
     site_id: str
     backend: str
-    scheduling: str
     reached: bool
     ok: bool
     failures: list[str] = field(default_factory=list)
@@ -128,7 +120,7 @@ class _RunOut:
     firings: list
 
 
-def _run_once(backend: str, scheduling: str, spec=None) -> _RunOut:
+def _run_once(backend: str, spec=None) -> _RunOut:
     from repro.core import ACANCloud, CloudConfig, FaultPlan, LayerSpec
     from repro.core.space import find_crashpoint
 
@@ -140,8 +132,7 @@ def _run_once(backend: str, scheduling: str, spec=None) -> _RunOut:
         # survivors before the daemon ever notices the death.
         n_handlers=1, epochs=1, n_samples=4, task_cap=256.0,
         pouch_size=50, lr=0.02, time_scale=1e-6, initial_timeout=0.1,
-        wall_limit=60.0, seed=0, scheduling=scheduling,
-        ts_backend=backend,
+        wall_limit=60.0, seed=0, ts_backend=backend,
         # Interval faults off: the crash point is the only fault.
         fault_plan=FaultPlan(interval=1e9),
     )
@@ -200,8 +191,7 @@ def _gate(site: Site, run: _RunOut, base: _RunOut, backend: str
             fails.append(f"crash fired but no {site.role} revival "
                          f"was recorded")
     return SiteResult(
-        site_id=site.site_id, backend=backend,
-        scheduling=_scheduling_for(site), reached=reached,
+        site_id=site.site_id, backend=backend, reached=reached,
         ok=not fails, failures=fails,
         revivals=run.manager_revivals + run.handler_revivals)
 
@@ -211,19 +201,15 @@ def sweep(sites: list[Site], backends: tuple[str, ...] = DEFAULT_BACKENDS,
     from repro.core.space import CrashSpec
 
     results: list[SiteResult] = []
-    baselines: dict[tuple[str, str], _RunOut] = {}
     for backend in backends:
+        base = _run_once(backend)
         for site in sites:
-            sched = _scheduling_for(site)
-            bkey = (backend, sched)
-            if bkey not in baselines:
-                baselines[bkey] = _run_once(backend, sched)
             spec = CrashSpec(site_id=site.site_id, role=site.role,
                              path=site.path, line=site.line,
                              end_line=site.end_line, nth=1, when="after")
             t0 = time.perf_counter()
-            run = _run_once(backend, sched, spec)
-            r = _gate(site, run, baselines[bkey], backend)
+            run = _run_once(backend, spec)
+            r = _gate(site, run, base, backend)
             r.seconds = time.perf_counter() - t0
             results.append(r)
             if verbose:
@@ -291,7 +277,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.list:
         for s in sites:
             print(f"{s.site_id}  {s.path}:{s.line}  "
-                  f"[{s.protection}]  sched={_scheduling_for(s)}")
+                  f"[{s.protection}]")
         print(f"crash-sweep plan: {len(sites)} site(s) x "
               f"{len(args.backends)} backend(s)")
         return 0

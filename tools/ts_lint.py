@@ -94,9 +94,6 @@ def _scope_for(path: str, progs: dict[str, tuple[KeySchema, ...]]
         if p.endswith(f"programs/{name}.py"):
             table.update({s.subject: s for s in schemas})
             return table
-    if "/ts_exec/" in p:
-        table.update({s.subject: s for s in progs["jax_sgd"]})
-        return table
     for schemas in progs.values():
         table.update({s.subject: s for s in schemas})
     return table
